@@ -141,6 +141,9 @@ def main(argv=None) -> int:
     ap.add_argument("--only", action="append", metavar="NAME",
                     help="run only this benchmark (repeatable)")
     args = ap.parse_args(argv)
+    from repro.launch.cache import enable_compile_cache
+
+    enable_compile_cache()
 
     by_name = {name: (title, fn) for name, title, fn in BENCHMARKS}
     if args.list:

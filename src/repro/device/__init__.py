@@ -11,7 +11,7 @@ on-device" item (see DESIGN.md Sec. 14):
                     int32 device-array slab behind the ordinary
                     ``Window`` contract (fallback ladder: on-device
                     atomics -> input/output-aliased slab update ->
-                    interpret mode, byte-exact on CPU CI; plus an
+                    interpret mode on CPU CI; plus an
                     ``io_callback`` shim for traced host-plane code).
   chunk_calculus.py jax-traceable SS/FSC/GSS/TSS/FAC2 closed forms,
                     index-for-index equal to ``core.chunk_calculus``.

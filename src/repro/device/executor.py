@@ -46,6 +46,7 @@ def execute_device(session, work_fn: Optional[Callable[[int, int], None]] = None
         costs=costs, slab=win.slab(), i_slot=i_slot, lp_slot=lp_slot,
         interpret=interpret)
     win.adopt(sched.slab, n_rmw=sched.n_rmw)
+    rt.schedule = sched
 
     t0s, t1s = schedule_timeline(sched, costs=costs)
     rows = []

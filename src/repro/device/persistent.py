@@ -32,6 +32,7 @@ import dataclasses
 import functools
 from typing import Optional
 
+import jax
 import numpy as np
 
 from repro.core.chunk_calculus import max_steps_bound
@@ -40,12 +41,15 @@ from .chunk_calculus import chunk_size_device, host_spec
 
 
 def _protocol_kernel(
-    ctr_in,      # (cap,) int32 -- the device window slab (aliased)
-    csum_ref,    # (N+1,) f32   -- prefix sum of per-iteration costs
-    ctr_out,     # (cap,) int32 -- aliased output (the same slab)
-    sched_ref,   # (S, 4) int32 -- rows (step, worker, start, size)
-    clocks_ref,  # (P,) f32     -- per-worker virtual busy clocks
-    counts_ref,  # (P,) int32   -- per-worker (per-block) claim counts
+    ctr_in,      # (cap,) int32 SMEM -- the device window slab (aliased)
+    csum_ref,    # (N+1,) f32   SMEM -- prefix sum of per-iteration costs
+    ctr_out,     # (cap,) int32 SMEM -- aliased output (the same slab)
+    steps_ref,   # (S,) int32   SMEM -- schedule rows: protocol step,
+    workers_ref,  # (S,) int32  SMEM --   granted worker (-1: no grant),
+    starts_ref,  # (S,) int32   SMEM --   first iteration,
+    sizes_ref,   # (S,) int32   SMEM --   iterations
+    clocks_ref,  # (P,) f32     SMEM -- per-worker virtual busy clocks
+    counts_ref,  # (P,) int32   SMEM -- per-worker (per-block) claim counts
     *,
     technique: str,
     N: int,
@@ -53,17 +57,32 @@ def _protocol_kernel(
     chunk: int,
     max_chunk: Optional[int],
     S: int,
+    cap: int,
     i_slot: int,
     lp_slot: int,
 ):
-    import jax
+    """Every operand is a scalar table in SMEM: the loop reads and writes
+    one counter, clock or schedule cell at a time, which the TPU only
+    allows in scalar memory (VMEM takes vector stores)."""
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
-    ctr_out[...] = ctr_in[...]
-    sched_ref[...] = jnp.full((S, 4), -1, jnp.int32)
-    clocks_ref[...] = jnp.zeros((P,), jnp.float32)
-    counts_ref[...] = jnp.zeros((P,), jnp.int32)
+    @pl.loop(0, cap)
+    def _copy_slab(j):
+        ctr_out[j] = ctr_in[j]
+
+    @pl.loop(0, S)
+    def _clear_row(s):  # rows past the last grant stay unread
+        workers_ref[s] = jnp.int32(-1)
+
+    @pl.loop(0, P)
+    def _clear_worker(w):
+        clocks_ref[w] = jnp.float32(0.0)
+        counts_ref[w] = jnp.int32(0)
+
+    def earliest_free(w, best):
+        # argmin over the clocks, ties to the lowest index
+        return jnp.where(clocks_ref[w] < clocks_ref[best], w, best)
 
     def step(s, carry):
         lp = ctr_out[lp_slot]
@@ -83,18 +102,53 @@ def _protocol_kernel(
             @pl.when(start < N)
             def _grant():
                 size = jnp.minimum(k, N - start)
-                w = jnp.argmin(clocks_ref[...]).astype(jnp.int32)
+                w = jax.lax.fori_loop(1, P, earliest_free, jnp.int32(0))
                 cost = csum_ref[start + size] - csum_ref[start]
                 clocks_ref[w] = clocks_ref[w] + cost
                 counts_ref[w] = counts_ref[w] + 1
-                sched_ref[s, 0] = i
-                sched_ref[s, 1] = w
-                sched_ref[s, 2] = start
-                sched_ref[s, 3] = size
+                steps_ref[s] = i
+                workers_ref[s] = w
+                starts_ref[s] = start
+                sizes_ref[s] = size
 
         return carry
 
     jax.lax.fori_loop(0, S, step, 0)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "technique", "N", "P", "chunk", "max_chunk", "S", "i_slot", "lp_slot",
+    "interpret"))
+def protocol_call(slab, csum, *, technique: str, N: int, P: int,
+                  chunk: int, max_chunk: Optional[int], S: int,
+                  i_slot: int, lp_slot: int, interpret: bool):
+    """The protocol kernel's ``pallas_call``: jittable, arrays in and out.
+
+    ``slab`` (cap,) int32 and ``csum`` (N+1,) f32 are device arrays;
+    every other argument is static.  Returns ``(slab, steps, workers,
+    starts, sizes, clocks, counts)`` with the slab aliased in place.
+    ``claim_schedule`` is the host wrapper around this call.
+    """
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    cap = int(slab.shape[0])
+    kern = functools.partial(
+        _protocol_kernel, technique=technique, N=N, P=P, chunk=chunk,
+        max_chunk=max_chunk, S=S, cap=cap, i_slot=i_slot, lp_slot=lp_slot)
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    i32, f32 = jnp.int32, jnp.float32
+    shapes = [(cap, i32), (S, i32), (S, i32), (S, i32), (S, i32),
+              (P, f32), (P, i32)]
+    return pl.pallas_call(
+        kern,
+        in_specs=[smem, smem],
+        out_specs=[smem] * len(shapes),
+        out_shape=[jax.ShapeDtypeStruct((n,), dt) for n, dt in shapes],
+        input_output_aliases={0: 0},
+        interpret=interpret,
+    )(slab, csum)
 
 
 @dataclasses.dataclass
@@ -173,9 +227,7 @@ def claim_schedule(
     partially-drained loop, exactly like the host runtime).  Runs under
     the Pallas interpreter on CPU (``kernels.resolve_interpret``).
     """
-    import jax
     import jax.numpy as jnp
-    from jax.experimental import pallas as pl
 
     from repro.kernels import resolve_interpret
 
@@ -198,38 +250,17 @@ def claim_schedule(
         raise ValueError(f"bad counter slots ({i_slot}, {lp_slot}) "
                          f"for slab of capacity {cap}")
 
-    kern = functools.partial(
-        _protocol_kernel, technique=technique, N=N, P=P, chunk=chunk,
-        max_chunk=max_chunk, S=S, i_slot=i_slot, lp_slot=lp_slot)
-    new_slab, sched, clocks, counts = pl.pallas_call(
-        kern,
-        grid=(1,),
-        in_specs=[
-            pl.BlockSpec((cap,), lambda g: (0,)),
-            pl.BlockSpec((N + 1,), lambda g: (0,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((cap,), lambda g: (0,)),
-            pl.BlockSpec((S, 4), lambda g: (0, 0)),
-            pl.BlockSpec((P,), lambda g: (0,)),
-            pl.BlockSpec((P,), lambda g: (0,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((cap,), jnp.int32),
-            jax.ShapeDtypeStruct((S, 4), jnp.int32),
-            jax.ShapeDtypeStruct((P,), jnp.float32),
-            jax.ShapeDtypeStruct((P,), jnp.int32),
-        ],
-        input_output_aliases={0: 0},
-        interpret=interpret,
-    )(slab, jnp.asarray(csum))
+    new_slab, steps, workers, starts, sizes, clocks, counts = protocol_call(
+        slab, jnp.asarray(csum), technique=technique, N=N, P=P, chunk=chunk,
+        max_chunk=max_chunk, S=S, i_slot=i_slot, lp_slot=lp_slot,
+        interpret=interpret)
 
-    sched = np.asarray(sched)
-    n = int((sched[:, 1] >= 0).sum())  # granted rows form a prefix
+    workers = np.asarray(workers)
+    n = int((workers >= 0).sum())  # granted rows form a prefix
     return DeviceSchedule(
         technique=technique, N=N, P=P, chunk=chunk,
-        steps=sched[:n, 0].copy(), workers=sched[:n, 1].copy(),
-        starts=sched[:n, 2].copy(), sizes=sched[:n, 3].copy(),
+        steps=np.asarray(steps)[:n], workers=workers[:n],
+        starts=np.asarray(starts)[:n], sizes=np.asarray(sizes)[:n],
         counts=np.asarray(counts, np.int64), clocks=np.asarray(clocks),
         slab=new_slab)
 

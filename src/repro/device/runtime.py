@@ -50,6 +50,9 @@ class DeviceRuntime(OneSidedRuntime):
         # kernel launch borrows the slab.
         window.slot(self._ki)
         window.slot(self._kl)
+        #: the ``DeviceSchedule`` the last ``executor="device"`` drain made
+        #: (the compute kernels take it as ``schedule=``)
+        self.schedule = None
 
     def counter_slots(self) -> "tuple[int, int]":
         """(i_slot, lp_slot) -- where the kernel finds this loop's counters."""

@@ -19,7 +19,9 @@ each rung -- ``capability_tier()`` reports which one this process gets):
                 update on the *same* device buffer -- one logical window,
                 never copied per claim.
   ``interpret`` CPU CI: the identical aliased-slab protocol runs under the
-                Pallas interpreter, byte-exact with the compiled path.
+                Pallas interpreter.  ``chip_smoke.py`` checks the compiled
+                path on the TPU: its schedules equal the host ``plan()``
+                index for index.
 
 Host-side ``fetch_add``/``read``/``reset`` satisfy the ordinary ``Window``
 contract, so every existing consumer (``OneSidedRuntime``, sessions,

@@ -1,4 +1,4 @@
-"""Pallas TPU kernels (validated in interpret mode on CPU).
+"""Pallas TPU kernels (interpret mode on CPU, compiled on the TPU).
 
   mandelbrot      -- paper app 2: escape-time z<-z^4+c (variable-cost loop)
   spin_image      -- paper app 1: PSIA histogram via one-hot reduction
@@ -12,6 +12,10 @@ variable-sized tile chunks through the device-window protocol of
 """
 import jax
 
+#: Kernel calls this process resolved to interpret mode.  A run on the
+#: chip asserts it stays 0: no kernel there may quietly interpret.
+interpreted_calls = 0
+
 
 def resolve_interpret(interpret=None) -> bool:
     """The one interpret-mode autodetect every kernel entry point shares.
@@ -22,8 +26,10 @@ def resolve_interpret(interpret=None) -> bool:
     submodule re-exports below so kernel modules can import it from this
     package without a cycle.
     """
+    global interpreted_calls
     if interpret is None:
-        return jax.default_backend() == "cpu"
+        interpret = jax.default_backend() == "cpu"
+    interpreted_calls += bool(interpret)
     return bool(interpret)
 
 

@@ -16,11 +16,13 @@ sliding-window masking stays on the static path.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.device.persistent import DeviceSchedule, claim_schedule
 
@@ -28,13 +30,13 @@ from .kernel import NEG_INF
 
 
 def _persistent_kernel(
-    nclaims_ref,  # (W,)   int32
-    starts_ref,   # (W, C) int32
-    sizes_ref,    # (W, C) int32
+    nclaims_ref,  # (W,)   int32 SMEM
+    starts_ref,   # (W*C,) int32 SMEM
+    sizes_ref,    # (W*C,) int32 SMEM
+    len_ref,      # (B,)   int32 SMEM -- valid kv length per batch row
     q_ref,        # (B*H,   nq*blk_q, D)
     k_ref,        # (B*Hkv, nk*blk_k, D)
     v_ref,        # (B*Hkv, nk*blk_k, D)
-    len_ref,      # (B,) int32 -- valid kv length per batch row
     o_ref,        # (B*H, nq*blk_q, D)
     *,
     scale: float,
@@ -46,6 +48,7 @@ def _persistent_kernel(
     Hkv: int,
     nq: int,
     D: int,
+    C: int,
 ):
     w = pl.program_id(0)
     group = H // Hkv
@@ -55,7 +58,7 @@ def _persistent_kernel(
         qi = tile - bh * nq
         b = bh // H
         kv = b * Hkv + (bh - b * H) // group
-        q_start = qi * blk_q
+        q_start = pl.multiple_of(qi * blk_q, blk_q)
         len_b = len_ref[b]
         # traced kv trip count: only the blocks this tile actually attends
         limit = jnp.minimum(len_b, q_start + blk_q) if causal else len_b
@@ -66,7 +69,7 @@ def _persistent_kernel(
 
         def kv_body(j, carry):
             m_prev, l_prev, acc = carry
-            k_start = j * blk_k
+            k_start = pl.multiple_of(j * blk_k, blk_k)
             k = k_ref[kv, pl.ds(k_start, blk_k), :].astype(jnp.float32)
             v = v_ref[kv, pl.ds(k_start, blk_k), :].astype(jnp.float32)
             s = jax.lax.dot_general(
@@ -97,13 +100,13 @@ def _persistent_kernel(
         o_ref[bh, pl.ds(q_start, blk_q), :] = (acc / safe).astype(o_ref.dtype)
 
     def claim_body(c, _):
-        st = starts_ref[w, c]
+        st = starts_ref[w * C + c]
 
         def step(t, __):
             tile_body(st + t)
             return __
 
-        jax.lax.fori_loop(0, sizes_ref[w, c], step, 0)
+        jax.lax.fori_loop(0, sizes_ref[w * C + c], step, 0)
         return _
 
     jax.lax.fori_loop(0, nclaims_ref[w], claim_body, 0)
@@ -125,6 +128,71 @@ def varlen_tile_costs(lengths, H: int, nq: int, blk_q: int, blk_k: int,
         limit = min(lengths[b], (qi + 1) * blk_q) if causal else lengths[b]
         costs[tile] = max(-(-int(limit) // blk_k), 0)
     return costs
+
+
+def _vmem_bytes(x) -> int:
+    """Bytes of ``x`` as a VMEM block: the minor dim fills 128 lanes."""
+    *lead, d = x.shape
+    return math.prod(lead) * -(-d // 128) * 128 * x.dtype.itemsize
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "causal", "scale", "blk_q", "blk_k", "interpret"))
+def persistent_call(nclaims, starts, sizes, lengths, q, k, v, *,
+                    causal: bool, scale: float, blk_q: int, blk_k: int,
+                    interpret: bool):
+    """The persistent kernel's ``pallas_call``: jittable, arrays in and out.
+
+    ``nclaims (W,)``, ``starts``/``sizes (W, C)`` are the per-worker claim
+    tables and ``lengths (B,)`` the kv extents, all int32; they ride in
+    SMEM as scalar-prefetch operands, because each program reads them one
+    entry at a time.  q, k and v are whole-array VMEM blocks, so VMEM
+    capacity caps the batch; the limit asked of the compiler is what
+    those blocks take.  Returns ``(B, H, Tq, D)``.
+    """
+    B, H, Tq, D = q.shape
+    _, Hkv, Tk, _ = k.shape
+    assert H % Hkv == 0, "GQA requires H divisible by Hkv"
+    workers, C = starts.shape
+
+    nq = -(-Tq // blk_q)
+    nk = -(-Tk // blk_k)
+    qp = jnp.pad(q, ((0, 0), (0, 0), (0, nq * blk_q - Tq), (0, 0)))
+    kp = jnp.pad(k, ((0, 0), (0, 0), (0, nk * blk_k - Tk), (0, 0)))
+    vp = jnp.pad(v, ((0, 0), (0, 0), (0, nk * blk_k - Tk), (0, 0)))
+    qp = qp.reshape(B * H, nq * blk_q, D)
+    kp = kp.reshape(B * Hkv, nk * blk_k, D)
+    vp = vp.reshape(B * Hkv, nk * blk_k, D)
+
+    kern = functools.partial(
+        _persistent_kernel,
+        scale=float(scale), causal=causal, seq_q=Tq,
+        blk_q=blk_q, blk_k=blk_k, H=H, Hkv=Hkv, nq=nq, D=D, C=C,
+    )
+
+    def whole(x):
+        return pl.BlockSpec(x.shape, lambda w, *_: (0,) * x.ndim)
+
+    # a block whose index never moves is held once; the f32 temporaries
+    # of one (q-block, kv-block) step need a few MiB on top
+    block_bytes = sum(map(_vmem_bytes, (qp, kp, vp, qp)))
+    out = pl.pallas_call(
+        kern,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(workers,),
+            in_specs=[whole(qp), whole(kp), whole(vp)],
+            # one shared output block: the claims partition the tile
+            # space, so together the workers write every (bh, q-block)
+            # slab exactly once
+            out_specs=whole(qp),
+        ),
+        out_shape=jax.ShapeDtypeStruct(qp.shape, q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=block_bytes + (4 << 20)),
+        interpret=interpret,
+    )(nclaims, starts.reshape(-1), sizes.reshape(-1), lengths, qp, kp, vp)
+    return out.reshape(B, H, nq * blk_q, D)[:, :, :Tq, :]
 
 
 def flash_attention_persistent(
@@ -154,18 +222,9 @@ def flash_attention_persistent(
 
     interpret = resolve_interpret(interpret)
     B, H, Tq, D = q.shape
-    _, Hkv, Tk, _ = k.shape
-    assert H % Hkv == 0, "GQA requires H divisible by Hkv"
+    Tk = k.shape[2]
     scale = (D ** -0.5) if scale is None else scale
-
     nq = -(-Tq // blk_q)
-    nk = -(-Tk // blk_k)
-    qp = jnp.pad(q, ((0, 0), (0, 0), (0, nq * blk_q - Tq), (0, 0)))
-    kp = jnp.pad(k, ((0, 0), (0, 0), (0, nk * blk_k - Tk), (0, 0)))
-    vp = jnp.pad(v, ((0, 0), (0, 0), (0, nk * blk_k - Tk), (0, 0)))
-    qp = qp.reshape(B * H, nq * blk_q, D)
-    kp = kp.reshape(B * Hkv, nk * blk_k, D)
-    vp = vp.reshape(B * Hkv, nk * blk_k, D)
 
     if lengths is None:
         lengths = np.full(B, Tk, np.int32)
@@ -185,30 +244,8 @@ def flash_attention_persistent(
             f"schedule is for (N={schedule.N}, P={schedule.P}), "
             f"this tile space needs (N={N}, P={workers})")
     nclaims, starts, sizes = schedule.worker_lists()
-    C = starts.shape[1]
-
-    kern = functools.partial(
-        _persistent_kernel,
-        scale=float(scale), causal=causal, seq_q=Tq,
-        blk_q=blk_q, blk_k=blk_k, H=H, Hkv=Hkv, nq=nq, D=D,
-    )
-    out = pl.pallas_call(
-        kern,
-        grid=(workers,),
-        in_specs=[
-            pl.BlockSpec((workers,), lambda w: (0,)),
-            pl.BlockSpec((workers, C), lambda w: (0, 0)),
-            pl.BlockSpec((workers, C), lambda w: (0, 0)),
-            pl.BlockSpec((B * H, nq * blk_q, D), lambda w: (0, 0, 0)),
-            pl.BlockSpec((B * Hkv, nk * blk_k, D), lambda w: (0, 0, 0)),
-            pl.BlockSpec((B * Hkv, nk * blk_k, D), lambda w: (0, 0, 0)),
-            pl.BlockSpec((B,), lambda w: (0,)),
-        ],
-        # one shared output block: the claims partition the tile space, so
-        # together the workers write every (bh, q-block) slab exactly once
-        out_specs=pl.BlockSpec((B * H, nq * blk_q, D), lambda w: (0, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B * H, nq * blk_q, D), q.dtype),
-        interpret=interpret,
-    )(jnp.asarray(nclaims), jnp.asarray(starts), jnp.asarray(sizes),
-      qp, kp, vp, jnp.asarray(lengths))
-    return out.reshape(B, H, nq * blk_q, D)[:, :, :Tq, :], schedule
+    out = persistent_call(
+        jnp.asarray(nclaims), jnp.asarray(starts), jnp.asarray(sizes),
+        jnp.asarray(lengths), q, k, v, causal=causal, scale=float(scale),
+        blk_q=blk_q, blk_k=blk_k, interpret=interpret)
+    return out, schedule

@@ -45,7 +45,6 @@ def escape_counts_tile(
     self-scheduled variant (persistent.py) so the two paths can never
     drift numerically -- their outputs are compared exactly in tests.
     """
-    shape = rows.shape
     dx = (xmax - xmin) / max(width - 1, 1)
     dy = (ymax - ymin) / max(height - 1, 1)
     cr = xmin + cols.astype(jnp.float32) * dx
@@ -62,16 +61,22 @@ def escape_counts_tile(
         nzr = zr4 + cr
         nzi = zi4 + ci
         mag2 = nzr * nzr + nzi * nzi
-        cnt = cnt + active.astype(jnp.int32)
-        still = active & (mag2 < 4.0)
+        cnt = cnt + active
+        live = active != 0
+        still = jnp.where(mag2 < 4.0, active, 0)
         # freeze escaped pixels so overflow cannot propagate NaNs
-        zr = jnp.where(active, nzr, zr)
-        zi = jnp.where(active, nzi, zi)
+        zr = jnp.where(live, nzr, zr)
+        zi = jnp.where(live, nzi, zi)
         return zr, zi, cnt, still
 
-    zeros = jnp.zeros(shape, jnp.float32)
-    init = (zeros, zeros, jnp.zeros(shape, jnp.int32),
-            jnp.ones(shape, jnp.bool_))
+    # The TPU compiler fixes each loop carry's tile layout from its initial
+    # value.  A constant tile (or one coordinate alone) is laid out
+    # replicated, and the body's full tiles cannot be relaid into that; nor
+    # can a bool mask be carried.  So every carry starts from a tile built
+    # out of both coordinates, and the live mask is carried as int32 0/1.
+    zero = cr * 0.0 + ci * 0.0
+    izero = zero.astype(jnp.int32)
+    init = (zero, zero, izero, izero + 1)
     _, _, cnt, _ = jax.lax.fori_loop(0, ct, body, init)
     # out-of-image padding pixels carry zeros (sliced off by the wrapper)
     in_image = (rows < height) & (cols < width)

@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.device.persistent import DeviceSchedule, claim_schedule
 
@@ -26,9 +27,9 @@ from .kernel import escape_counts_tile
 
 
 def _persistent_kernel(
-    nclaims_ref,  # (W,)   int32 -- claims per worker
-    starts_ref,   # (W, C) int32 -- first tile of each claim
-    sizes_ref,    # (W, C) int32 -- tiles in each claim
+    nclaims_ref,  # (W,)   int32 SMEM -- claims per worker
+    starts_ref,   # (W*C,) int32 SMEM -- first tile of each claim
+    sizes_ref,    # (W*C,) int32 SMEM -- tiles in each claim
     out_ref,      # (gh*block_h, gw*block_w) int32 -- whole counts image
     *,
     ct: int,
@@ -46,28 +47,69 @@ def _persistent_kernel(
     w = pl.program_id(0)
 
     def claim_body(c, _):
-        st = starts_ref[w, c]
-        sz = sizes_ref[w, c]
+        st = starts_ref[w * C + c]
+        sz = sizes_ref[w * C + c]
 
         def tile_body(t, __):
             tile = st + t
             ti = tile // gw
             tj = tile - ti * gw
-            rows = ti * block_h + jax.lax.broadcasted_iota(
+            r0 = pl.multiple_of(ti * block_h, block_h)
+            c0 = pl.multiple_of(tj * block_w, block_w)
+            rows = r0 + jax.lax.broadcasted_iota(
                 jnp.int32, (block_h, block_w), 0)
-            cols = tj * block_w + jax.lax.broadcasted_iota(
+            cols = c0 + jax.lax.broadcasted_iota(
                 jnp.int32, (block_h, block_w), 1)
             cnt = escape_counts_tile(
                 rows, cols, ct=ct, width=width, height=height,
                 xmin=xmin, xmax=xmax, ymin=ymin, ymax=ymax)
-            out_ref[pl.ds(ti * block_h, block_h),
-                    pl.ds(tj * block_w, block_w)] = cnt
+            out_ref[pl.ds(r0, block_h), pl.ds(c0, block_w)] = cnt
             return __
 
         jax.lax.fori_loop(0, sz, tile_body, 0)
         return _
 
     jax.lax.fori_loop(0, nclaims_ref[w], claim_body, 0)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "width", "height", "ct", "xlim", "ylim", "block_h", "block_w",
+    "interpret"))
+def persistent_call(nclaims, starts, sizes, *, width: int, height: int,
+                    ct: int, xlim, ylim, block_h: int, block_w: int,
+                    interpret: bool):
+    """The persistent kernel's ``pallas_call``: jittable, arrays in and out.
+
+    ``nclaims (W,)``, ``starts``/``sizes (W, C)`` int32 are the per-worker
+    claim tables (``DeviceSchedule.worker_lists``); they ride in SMEM as
+    scalar-prefetch operands, because each program reads them one entry
+    at a time.  Returns the padded ``(gh*block_h, gw*block_w)`` image.
+    """
+    workers, C = starts.shape
+    gh = -(-height // block_h)
+    gw = -(-width // block_w)
+    kern = functools.partial(
+        _persistent_kernel,
+        ct=ct, width=width, height=height,
+        xmin=float(xlim[0]), xmax=float(xlim[1]),
+        ymin=float(ylim[0]), ymax=float(ylim[1]),
+        block_h=block_h, block_w=block_w, gw=gw, C=C,
+    )
+    shape = (gh * block_h, gw * block_w)
+    return pl.pallas_call(
+        kern,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(workers,),
+            in_specs=[],
+            # every program maps to the same (whole-image) block: the
+            # claims partition [0, N), so together the workers write
+            # every tile once
+            out_specs=pl.BlockSpec(shape, lambda w, *_: (0, 0)),
+        ),
+        out_shape=jax.ShapeDtypeStruct(shape, jnp.int32),
+        interpret=interpret,
+    )(nclaims, starts.reshape(-1), sizes.reshape(-1))
 
 
 def mandelbrot_persistent(
@@ -110,29 +152,11 @@ def mandelbrot_persistent(
             f"schedule is for (N={schedule.N}, P={schedule.P}), "
             f"this grid needs (N={N}, P={workers})")
     nclaims, starts, sizes = schedule.worker_lists()
-    C = starts.shape[1]
-
-    kern = functools.partial(
-        _persistent_kernel,
-        ct=ct, width=width, height=height,
-        xmin=float(xlim[0]), xmax=float(xlim[1]),
-        ymin=float(ylim[0]), ymax=float(ylim[1]),
-        block_h=block_h, block_w=block_w, gw=gw, C=C,
-    )
-    out = pl.pallas_call(
-        kern,
-        grid=(workers,),
-        in_specs=[
-            pl.BlockSpec((workers,), lambda w: (0,)),
-            pl.BlockSpec((workers, C), lambda w: (0, 0)),
-            pl.BlockSpec((workers, C), lambda w: (0, 0)),
-        ],
-        # every program maps to the same (whole-image) block: the claims
-        # partition [0, N), so together the workers write every tile once
-        out_specs=pl.BlockSpec((gh * block_h, gw * block_w), lambda w: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((gh * block_h, gw * block_w), jnp.int32),
-        interpret=interpret,
-    )(jnp.asarray(nclaims), jnp.asarray(starts), jnp.asarray(sizes))
+    out = persistent_call(
+        jnp.asarray(nclaims), jnp.asarray(starts), jnp.asarray(sizes),
+        width=width, height=height, ct=ct, xlim=tuple(xlim),
+        ylim=tuple(ylim), block_h=block_h, block_w=block_w,
+        interpret=interpret)
     return out[:height, :width], schedule
 
 

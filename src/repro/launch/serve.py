@@ -1,7 +1,10 @@
 """Serving driver: batched generation + DLS continuous-batching stats.
 
     PYTHONPATH=src python -m repro.launch.serve --arch tinyllama-1.1b \
-        --reduced --requests 64 --batch 8
+        --requests 64 --batch 8
+
+Published widths by default; ``--reduced`` serves the reduced
+same-family config (CPU-sized).
 """
 from __future__ import annotations
 
@@ -12,6 +15,7 @@ import jax
 import numpy as np
 
 from repro.configs import ARCHS, get_config
+from repro.launch.cache import enable_compile_cache
 from repro.models import api
 from repro.serve import ContinuousBatcher, Engine, Request
 
@@ -19,13 +23,15 @@ from repro.serve import ContinuousBatcher, Engine, Request
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="tinyllama-1.1b", choices=sorted(ARCHS))
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action="store_true",
+                    help="reduced same-family config (CPU-sized)")
     ap.add_argument("--requests", type=int, default=64)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--max-new", type=int, default=16)
     ap.add_argument("--technique", default="gss")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch).reduced() if args.reduced else get_config(args.arch)
     params = api.init_params(jax.random.key(args.seed), cfg)

@@ -17,6 +17,7 @@ import argparse
 import jax
 
 from repro.configs import ARCHS, get_config
+from repro.launch.cache import enable_compile_cache
 from repro.optim import AdamWConfig
 from repro.train import TrainConfig, Trainer
 
@@ -41,6 +42,7 @@ def main():
     ap.add_argument("--host-id", type=int, default=0)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

@@ -52,15 +52,20 @@ class Engine:
         self._decode = jax.jit(
             lambda p, t, c: api.decode_step(p, cfg, t, c, ctx=ctx))
 
-    def generate(self, prompts: np.ndarray, max_new: int) -> np.ndarray:
-        """prompts (B, Tp) -> tokens (B, max_new), greedy."""
+    def prefill(self, prompts: np.ndarray, max_new: int):
+        """prompts (B, Tp) -> (last-position logits (B, vocab), cache),
+        the cache sized for ``max_new`` more tokens."""
         B, Tp = prompts.shape
         cache = api.init_cache(self.cfg, B, Tp + max_new,
                                src_len=Tp if self.cfg.is_encdec else None)
         batch = {"tokens": jnp.asarray(prompts)}
         if self.cfg.is_encdec:
             batch["src_embeds"] = api.frontend_stub_embeds(self.cfg, B, Tp)
-        logits, cache = self._prefill(self.params, batch, cache)
+        return self._prefill(self.params, batch, cache)
+
+    def generate(self, prompts: np.ndarray, max_new: int) -> np.ndarray:
+        """prompts (B, Tp) -> tokens (B, max_new), greedy."""
+        logits, cache = self.prefill(prompts, max_new)
         out = []
         tok = jnp.argmax(logits, -1).astype(jnp.int32)
         for _ in range(max_new):
