@@ -20,6 +20,7 @@ from typing import Callable, Optional
 
 import numpy as np
 
+from repro import tracing
 from repro.core.scheduler import Claim
 
 from .persistent import claim_schedule, schedule_timeline
@@ -45,21 +46,22 @@ def execute_device(session, work_fn: Optional[Callable[[int, int], None]] = None
         chunk=spec.min_chunk, max_chunk=spec.max_chunk,
         costs=costs, slab=win.slab(), i_slot=i_slot, lp_slot=lp_slot,
         interpret=interpret)
-    win.adopt(sched.slab, n_rmw=sched.n_rmw)
-    rt.schedule = sched
+    with tracing.span("report"):
+        win.adopt(sched.slab, n_rmw=sched.n_rmw)
+        rt.schedule = sched
 
-    t0s, t1s = schedule_timeline(sched, costs=costs)
-    rows = []
-    for r in range(sched.n_steps):
-        w = int(sched.workers[r])
-        c = Claim(step=int(sched.steps[r]), start=int(sched.starts[r]),
-                  size=int(sched.sizes[r]))
-        session.log_claim(w, c)
-        if work_fn is not None:
-            work_fn(c.start, c.stop)
-        rows.append((w, c, float(t0s[r]), float(t1s[r])))
-    # record in canonical completion order (matches the sim executor)
-    for w, c, t0, t1 in sorted(rows, key=lambda x: (x[2], x[3], x[0])):
-        session.record_remote(w, c.size, t1 - t0, sched_seconds=0.0,
-                              claim=c, t_start=t0, t_end=t1)
-    return session.report("device", wall_time=sched.makespan())
+        t0s, t1s = schedule_timeline(sched, costs=costs)
+        rows = []
+        for r in range(sched.n_steps):
+            w = int(sched.workers[r])
+            c = Claim(step=int(sched.steps[r]), start=int(sched.starts[r]),
+                      size=int(sched.sizes[r]))
+            session.log_claim(w, c)
+            if work_fn is not None:
+                work_fn(c.start, c.stop)
+            rows.append((w, c, float(t0s[r]), float(t1s[r])))
+        # record in canonical completion order (matches the sim executor)
+        for w, c, t0, t1 in sorted(rows, key=lambda x: (x[2], x[3], x[0])):
+            session.record_remote(w, c.size, t1 - t0, sched_seconds=0.0,
+                                  claim=c, t_start=t0, t_end=t1)
+        return session.report("device", wall_time=sched.makespan())

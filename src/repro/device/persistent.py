@@ -35,6 +35,7 @@ from typing import Optional
 import jax
 import numpy as np
 
+from repro import tracing
 from repro.core.chunk_calculus import max_steps_bound
 
 from .chunk_calculus import chunk_size_device, host_spec
@@ -148,6 +149,7 @@ def protocol_call(slab, csum, *, technique: str, N: int, P: int,
         out_shape=[jax.ShapeDtypeStruct((n,), dt) for n, dt in shapes],
         input_output_aliases={0: 0},
         interpret=interpret,
+        name="dls_protocol",
     )(slab, csum)
 
 
@@ -231,38 +233,47 @@ def claim_schedule(
 
     from repro.kernels import resolve_interpret
 
-    interpret = resolve_interpret(interpret)
-    spec = host_spec(technique, N, P, chunk, max_chunk)
-    S = int(max_steps or max_steps_bound(spec))
+    with tracing.span("claim") as claim:
+        interpret = resolve_interpret(interpret)
+        spec = host_spec(technique, N, P, chunk, max_chunk)
+        S = int(max_steps or max_steps_bound(spec))
+        with tracing.span("claim.costs"):
+            if costs is None:
+                costs = np.ones(N, np.float32)
+            costs = np.asarray(costs, np.float64)
+            if costs.shape != (N,):
+                raise ValueError(
+                    f"costs must have shape ({N},), got {costs.shape}")
+            csum = np.zeros(N + 1, np.float32)
+            np.cumsum(costs, out=csum[1:])
+            if slab is None:
+                slab = jnp.zeros(max(i_slot, lp_slot) + 1, jnp.int32)
+            csum = jnp.asarray(csum)
+        cap = int(slab.shape[0])
+        if not (0 <= i_slot < cap and 0 <= lp_slot < cap
+                and i_slot != lp_slot):
+            raise ValueError(f"bad counter slots ({i_slot}, {lp_slot}) "
+                             f"for slab of capacity {cap}")
 
-    if costs is None:
-        costs = np.ones(N, np.float32)
-    costs = np.asarray(costs, np.float64)
-    if costs.shape != (N,):
-        raise ValueError(f"costs must have shape ({N},), got {costs.shape}")
-    csum = np.zeros(N + 1, np.float32)
-    np.cumsum(costs, out=csum[1:])
+        with tracing.launch("claim.launch", protocol_call):
+            new_slab, steps, workers, starts, sizes, clocks, counts = \
+                protocol_call(slab, csum, technique=technique, N=N, P=P,
+                              chunk=chunk, max_chunk=max_chunk, S=S,
+                              i_slot=i_slot, lp_slot=lp_slot,
+                              interpret=interpret)
 
-    if slab is None:
-        slab = jnp.zeros(max(i_slot, lp_slot) + 1, jnp.int32)
-    cap = int(slab.shape[0])
-    if not (0 <= i_slot < cap and 0 <= lp_slot < cap and i_slot != lp_slot):
-        raise ValueError(f"bad counter slots ({i_slot}, {lp_slot}) "
-                         f"for slab of capacity {cap}")
-
-    new_slab, steps, workers, starts, sizes, clocks, counts = protocol_call(
-        slab, jnp.asarray(csum), technique=technique, N=N, P=P, chunk=chunk,
-        max_chunk=max_chunk, S=S, i_slot=i_slot, lp_slot=lp_slot,
-        interpret=interpret)
-
-    workers = np.asarray(workers)
-    n = int((workers >= 0).sum())  # granted rows form a prefix
-    return DeviceSchedule(
-        technique=technique, N=N, P=P, chunk=chunk,
-        steps=np.asarray(steps)[:n], workers=workers[:n],
-        starts=np.asarray(starts)[:n], sizes=np.asarray(sizes)[:n],
-        counts=np.asarray(counts, np.int64), clocks=np.asarray(clocks),
-        slab=new_slab)
+        with tracing.span("claim.readback"):
+            workers = np.asarray(workers)
+            n = int((workers >= 0).sum())  # granted rows form a prefix
+            sched = DeviceSchedule(
+                technique=technique, N=N, P=P, chunk=chunk,
+                steps=np.asarray(steps)[:n], workers=workers[:n],
+                starts=np.asarray(starts)[:n], sizes=np.asarray(sizes)[:n],
+                counts=np.asarray(counts, np.int64),
+                clocks=np.asarray(clocks), slab=new_slab)
+        if tracing.enabled():
+            claim.set_metadata(steps=S, claims=n)
+    return sched
 
 
 def schedule_timeline(schedule: DeviceSchedule, costs=None):

@@ -28,6 +28,7 @@ from typing import Callable, Iterator, List, Optional
 
 import numpy as np
 
+from repro import tracing
 from repro.core.chunk_calculus import ADAPTIVE, POLICY_DRIVEN, WEIGHTED, LoopSpec
 from repro.core.rma import HierarchicalWindow, SimWindow
 from repro.core.scheduler import Claim, HierarchicalRuntime, OneSidedRuntime
@@ -469,39 +470,43 @@ def loop(
             "and have no effect on an explicitly chosen technique "
             "(pass executor costs to execute(..., executor=\"sim\") instead)",
             stacklevel=2)
-    spec_weights = None
-    if (weights is not None and not isinstance(weights, str)
-            and hasattr(weights, "__len__") and len(weights) == P):
-        spec_weights = tuple(float(w) for w in weights)
-    spec = LoopSpec(technique, N=N, P=P, weights=spec_weights,
-                    min_chunk=min_chunk, max_chunk=max_chunk)
-    rt = make_runtime(spec, runtime=runtime, window=window, loop_id=loop_id,
-                      nodes=nodes, inner_technique=inner_technique)
-    # Adaptive techniques measure PE performance online: with no explicit
-    # policy they auto-adopt their own (technique-named) telemetry policy.
-    # The claim-level technique decides (inner for hierarchical runtimes,
-    # the outer falls back to node-aggregated telemetry either way).
-    claim_tech = (inner_technique or "ss") if runtime == "hierarchical" \
-        else technique
-    if weights is None:
-        for t in (claim_tech, technique):
-            if t in ADAPTIVE:
-                weights = t
-                break
-    policy = make_weight_policy(weights, P)
-    # ``POLICY_DRIVEN`` (chunk_calculus) is the single source of truth for
-    # which techniques consume a weight policy -- this warning, the policy
-    # name registry, and the docs tables all derive from it.
-    weighted = technique in POLICY_DRIVEN or (
-        runtime == "hierarchical" and (inner_technique or "ss") in POLICY_DRIVEN)
-    if weights is not None and not weighted \
-            and not isinstance(policy, UniformWeights):
-        warnings.warn(
-            f"technique {technique!r} ignores weights (only techniques in "
-            f"{POLICY_DRIVEN} consume a weight policy); the supplied policy "
-            f"will have no effect",
-            stacklevel=2)
-    session = DLSession(spec, rt, weights=policy,
-                        record_metrics=record_metrics)
-    session.auto_decision = auto_decision
-    return session
+    with tracing.span("session.open"):
+        spec_weights = None
+        if (weights is not None and not isinstance(weights, str)
+                and hasattr(weights, "__len__") and len(weights) == P):
+            spec_weights = tuple(float(w) for w in weights)
+        spec = LoopSpec(technique, N=N, P=P, weights=spec_weights,
+                        min_chunk=min_chunk, max_chunk=max_chunk)
+        rt = make_runtime(spec, runtime=runtime, window=window,
+                          loop_id=loop_id, nodes=nodes,
+                          inner_technique=inner_technique)
+        # Adaptive techniques measure PE performance online: with no
+        # explicit policy they auto-adopt their own (technique-named)
+        # telemetry policy.  The claim-level technique decides (inner for
+        # hierarchical runtimes, the outer falls back to node-aggregated
+        # telemetry either way).
+        claim_tech = (inner_technique or "ss") if runtime == "hierarchical" \
+            else technique
+        if weights is None:
+            for t in (claim_tech, technique):
+                if t in ADAPTIVE:
+                    weights = t
+                    break
+        policy = make_weight_policy(weights, P)
+        # ``POLICY_DRIVEN`` (chunk_calculus) is the single source of truth
+        # for which techniques consume a weight policy -- this warning, the
+        # policy name registry, and the docs tables all derive from it.
+        weighted = technique in POLICY_DRIVEN or (
+            runtime == "hierarchical"
+            and (inner_technique or "ss") in POLICY_DRIVEN)
+        if weights is not None and not weighted \
+                and not isinstance(policy, UniformWeights):
+            warnings.warn(
+                f"technique {technique!r} ignores weights (only techniques "
+                f"in {POLICY_DRIVEN} consume a weight policy); the supplied "
+                f"policy will have no effect",
+                stacklevel=2)
+        session = DLSession(spec, rt, weights=policy,
+                            record_metrics=record_metrics)
+        session.auto_decision = auto_decision
+        return session

@@ -24,6 +24,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import tracing
 from repro.device.persistent import DeviceSchedule, claim_schedule
 
 from .kernel import NEG_INF
@@ -191,6 +192,7 @@ def persistent_call(nclaims, starts, sizes, lengths, q, k, v, *,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=block_bytes + (4 << 20)),
         interpret=interpret,
+        name="attention_persistent",
     )(nclaims, starts.reshape(-1), sizes.reshape(-1), lengths, qp, kp, vp)
     return out.reshape(B, H, nq * blk_q, D)[:, :, :Tq, :]
 
@@ -235,7 +237,9 @@ def flash_attention_persistent(
     N = B * H * nq
     if schedule is None:
         if costs is None:
-            costs = varlen_tile_costs(lengths, H, nq, blk_q, blk_k, causal)
+            with tracing.span("tile_costs"):
+                costs = varlen_tile_costs(lengths, H, nq, blk_q, blk_k,
+                                          causal)
         schedule = claim_schedule(
             technique, N, workers, chunk=chunk, costs=costs,
             interpret=interpret)
@@ -243,9 +247,11 @@ def flash_attention_persistent(
         raise ValueError(
             f"schedule is for (N={schedule.N}, P={schedule.P}), "
             f"this tile space needs (N={N}, P={workers})")
-    nclaims, starts, sizes = schedule.worker_lists()
-    out = persistent_call(
-        jnp.asarray(nclaims), jnp.asarray(starts), jnp.asarray(sizes),
-        jnp.asarray(lengths), q, k, v, causal=causal, scale=float(scale),
-        blk_q=blk_q, blk_k=blk_k, interpret=interpret)
+    with tracing.span("tables"):
+        tables = [jnp.asarray(t) for t in (*schedule.worker_lists(),
+                                           lengths)]
+    with tracing.launch("compute.launch", persistent_call):
+        out = persistent_call(
+            *tables, q, k, v, causal=causal, scale=float(scale),
+            blk_q=blk_q, blk_k=blk_k, interpret=interpret)
     return out, schedule

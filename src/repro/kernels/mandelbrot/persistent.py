@@ -21,6 +21,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import tracing
 from repro.device.persistent import DeviceSchedule, claim_schedule
 
 from .kernel import escape_counts_tile
@@ -109,6 +110,7 @@ def persistent_call(nclaims, starts, sizes, *, width: int, height: int,
         ),
         out_shape=jax.ShapeDtypeStruct(shape, jnp.int32),
         interpret=interpret,
+        name="mandelbrot_persistent",
     )(nclaims, starts.reshape(-1), sizes.reshape(-1))
 
 
@@ -151,12 +153,13 @@ def mandelbrot_persistent(
         raise ValueError(
             f"schedule is for (N={schedule.N}, P={schedule.P}), "
             f"this grid needs (N={N}, P={workers})")
-    nclaims, starts, sizes = schedule.worker_lists()
-    out = persistent_call(
-        jnp.asarray(nclaims), jnp.asarray(starts), jnp.asarray(sizes),
-        width=width, height=height, ct=ct, xlim=tuple(xlim),
-        ylim=tuple(ylim), block_h=block_h, block_w=block_w,
-        interpret=interpret)
+    with tracing.span("tables"):
+        tables = [jnp.asarray(t) for t in schedule.worker_lists()]
+    with tracing.launch("compute.launch", persistent_call):
+        out = persistent_call(
+            *tables, width=width, height=height, ct=ct, xlim=tuple(xlim),
+            ylim=tuple(ylim), block_h=block_h, block_w=block_w,
+            interpret=interpret)
     return out[:height, :width], schedule
 
 

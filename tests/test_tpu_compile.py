@@ -69,7 +69,9 @@ def test_protocol_kernel_compiles(one_chip, technique):
     fn = functools.partial(
         protocol_call, technique=technique, N=N_TILES, P=P, chunk=1,
         max_chunk=None, S=S, i_slot=0, lp_slot=1, interpret=False)
-    _compile(one_chip, fn, ((256,), jnp.int32), ((N_TILES + 1,), jnp.float32))
+    text = _compile(one_chip, fn, ((256,), jnp.int32),
+                    ((N_TILES + 1,), jnp.float32))
+    assert "%dls_protocol" in text  # the kernel's name in the device trace
 
 
 def test_static_mandelbrot_compiles(one_chip):
@@ -89,8 +91,9 @@ def test_persistent_mandelbrot_compiles(one_chip):
         persistent_call, width=WIDTH, height=WIDTH, ct=CT,
         xlim=(-2.0, 1.0), ylim=(-1.5, 1.5), block_h=128, block_w=128,
         interpret=False)
-    _compile(one_chip, fn, ((P,), jnp.int32), ((P, C), jnp.int32),
-             ((P, C), jnp.int32))
+    text = _compile(one_chip, fn, ((P,), jnp.int32), ((P, C), jnp.int32),
+                    ((P, C), jnp.int32))
+    assert "%mandelbrot_persistent" in text
 
 
 def test_static_flash_attention_compiles(one_chip):
@@ -122,11 +125,12 @@ def test_persistent_flash_attention_compiles(one_chip, B, fits):
                            interpret=False)
     refused = pytest.raises(jax.errors.JaxRuntimeError, match="vmem")
     with contextlib.nullcontext() if fits else refused:
-        _compile(one_chip, fn, ((P,), jnp.int32), ((P, C), jnp.int32),
-                 ((P, C), jnp.int32), ((B,), jnp.int32),
-                 ((B, HEADS, T, HEAD_DIM), jnp.bfloat16),
-                 ((B, KV_HEADS, T, HEAD_DIM), jnp.bfloat16),
-                 ((B, KV_HEADS, T, HEAD_DIM), jnp.bfloat16))
+        text = _compile(one_chip, fn, ((P,), jnp.int32), ((P, C), jnp.int32),
+                        ((P, C), jnp.int32), ((B,), jnp.int32),
+                        ((B, HEADS, T, HEAD_DIM), jnp.bfloat16),
+                        ((B, KV_HEADS, T, HEAD_DIM), jnp.bfloat16),
+                        ((B, KV_HEADS, T, HEAD_DIM), jnp.bfloat16))
+        assert "%attention_persistent" in text
 
 
 def test_spin_images_compile(one_chip):

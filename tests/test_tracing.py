@@ -1,0 +1,175 @@
+"""Program spans and counters (``repro.tracing``) under the JAX profiler.
+
+Each traced test starts and stops the profiler itself, in ``try/finally``:
+a process holds one profiler session at a time.  Kernels run in interpret
+mode on the CPU, at small sizes.
+"""
+import subprocess
+import sys
+from pathlib import Path
+
+import jax
+import numpy as np
+import pytest
+
+from repro import dls, tracing
+from repro.core.chunk_calculus import max_steps_bound
+from repro.device import host_spec
+from repro.device.persistent import claim_schedule
+from repro.kernels import flash_attention_persistent, mandelbrot_persistent
+
+ROOT = Path(__file__).resolve().parent.parent
+P, TILE = 8, 8
+
+CLAIM = {"repro.claim.costs": "repro.claim",
+         "repro.claim.launch": "repro.claim",
+         "repro.claim.readback": "repro.claim",
+         "repro.claim": None, "repro.tables": None,
+         "repro.compute.launch": None}
+NESTING = {
+    "mandelbrot": dict(CLAIM, **{"repro.session.open": None,
+                                 "repro.report": None}),
+    "attention": dict(CLAIM, **{"repro.tile_costs": None}),
+}
+
+
+def _traced(tmp_path, fn):
+    """(``fn()``, the ``repro.*`` host spans recorded while it ran), the
+    spans as dicts in order of their start."""
+    from jax.profiler import ProfileData
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        out = fn()
+    finally:
+        jax.profiler.stop_trace()
+    path = next(tmp_path.rglob("*.xplane.pb"))
+    spans = [dict(thread=line.name, name=e.name, start=e.start_ns,
+                  end=e.start_ns + e.duration_ns, stats=dict(e.stats))
+             for plane in ProfileData.from_file(str(path)).planes
+             if not plane.name.startswith("/device")
+             for line in plane.lines for e in line.events
+             if e.name.startswith(tracing.PREFIX)]
+    return out, sorted(spans, key=lambda s: s["start"])
+
+
+def _parent(s, spans):
+    """Name of the innermost span enclosing ``s`` on its thread, or None."""
+    outer = [p for p in spans if p is not s and p["thread"] == s["thread"]
+             and p["start"] <= s["start"] and s["end"] <= p["end"]]
+    return min(outer, key=lambda p: p["end"] - p["start"])["name"] \
+        if outer else None
+
+
+def _mandel_drain(N, technique):
+    """One self-scheduled Mandelbrot loop of N tiles through the session
+    path, as a user of ``repro.dls`` writes it."""
+    side = int(round(N ** 0.5)) * TILE
+    s = dls.loop(N, technique=technique, P=P, runtime="device")
+    s.execute(None, executor="device", costs=np.ones(N))
+    out, sched = mandelbrot_persistent(
+        side, side, ct=8, block_h=TILE, block_w=TILE, workers=P,
+        schedule=s.runtime.schedule)
+    jax.block_until_ready(out)
+    return sched
+
+
+def _attention_drain():
+    B, H, T, D = 2, 2, 256, 128
+    q, k, v = (jax.random.normal(key, (B, H, T, D))
+               for key in jax.random.split(jax.random.key(0), 3))
+    out, sched = flash_attention_persistent(
+        q, k, v, lengths=[256, 100], causal=True, technique="gss",
+        workers=P, blk_q=128, blk_k=128)
+    jax.block_until_ready(out)
+    return sched
+
+
+DRAINS = {"mandelbrot": lambda: _mandel_drain(81, "gss"),
+          "attention": _attention_drain}
+
+
+@pytest.mark.parametrize("path", sorted(DRAINS))
+def test_spans_nest_as_documented(tmp_path, path):
+    _, spans = _traced(tmp_path, DRAINS[path])
+    got = {s["name"]: _parent(s, spans) for s in spans}
+    assert got == NESTING[path]
+    assert len(spans) == len(NESTING[path])  # each phase once a drain
+
+
+def test_claim_counters_are_the_grants_and_the_loop_length(tmp_path):
+    sched, spans = _traced(tmp_path, lambda: _mandel_drain(81, "gss"))
+    (claim,) = [s for s in spans if s["name"] == "repro.claim"]
+    S = int(max_steps_bound(host_spec("gss", 81, P)))
+    assert claim["stats"] == {"claims": sched.n_steps, "steps": S}
+    assert (sched.n_steps, S) == (17, 100)
+
+
+def test_launch_counts_a_compile_on_the_first_call_only(tmp_path):
+    def twice():
+        # a signature no other test compiles in this process
+        for _ in range(2):
+            sched = claim_schedule("tss", 15, 3)
+            jax.block_until_ready(mandelbrot_persistent(
+                40, 24, ct=4, block_h=TILE, block_w=TILE, workers=3,
+                schedule=sched)[0])
+
+    _, spans = _traced(tmp_path, twice)
+    for name in ("repro.claim.launch", "repro.compute.launch"):
+        assert [s["stats"] for s in spans if s["name"] == name] == \
+            [{"compiled": 1}, {"compiled": 0}]
+
+
+def test_span_count_per_drain_does_not_grow_with_the_claims(tmp_path):
+    few, spans_few = _traced(tmp_path / "gss",
+                             lambda: _mandel_drain(81, "gss"))
+    many, spans_many = _traced(tmp_path / "ss",
+                               lambda: _mandel_drain(1296, "ss"))
+    assert (few.n_steps, many.n_steps) == (17, 1296)
+    assert [s["name"] for s in spans_few] == [s["name"] for s in spans_many]
+    (claim,) = [s for s in spans_many if s["name"] == "repro.claim"]
+    assert claim["stats"] == {"claims": 1296, "steps": 1296}
+
+
+class _Jitted:
+    """Counts how often a launch span asks for the cache size."""
+
+    def __init__(self):
+        self.asked = 0
+
+    def _cache_size(self):
+        self.asked += 1
+        return self.asked
+
+
+def test_without_a_profiler_spans_record_nothing_and_count_nothing(
+        tmp_path):
+    fn = _Jitted()
+    assert not tracing.enabled()
+    with tracing.span("off", claims=3):
+        with tracing.launch("off.launch", fn):
+            pass
+    assert fn.asked == 0  # counters are not computed while off
+
+    def on():
+        assert tracing.enabled()
+        with tracing.launch("on.launch", fn):
+            pass
+
+    _, spans = _traced(tmp_path, on)
+    assert [s["name"] for s in spans] == ["repro.on.launch"]
+    assert spans[0]["stats"] == {"compiled": 1} and fn.asked == 2
+
+
+def test_host_runtimes_stay_free_of_jax():
+    code = ("import sys; from repro import dls; "
+            "s = dls.loop(16, 'ss', P=2); "
+            "s.execute(lambda a, b: None, executor='serial'); "
+            "assert 'jax' not in sys.modules")
+    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, timeout=120,
+                       env={"PYTHONPATH": str(ROOT / "src"),
+                            "PATH": "/usr/bin:/bin"})
+    assert r.returncode == 0, r.stderr
