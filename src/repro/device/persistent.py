@@ -117,19 +117,11 @@ def _protocol_kernel(
     jax.lax.fori_loop(0, S, step, 0)
 
 
-@functools.partial(jax.jit, static_argnames=(
-    "technique", "N", "P", "chunk", "max_chunk", "S", "i_slot", "lp_slot",
-    "interpret"))
-def protocol_call(slab, csum, *, technique: str, N: int, P: int,
-                  chunk: int, max_chunk: Optional[int], S: int,
-                  i_slot: int, lp_slot: int, interpret: bool):
-    """The protocol kernel's ``pallas_call``: jittable, arrays in and out.
-
-    ``slab`` (cap,) int32 and ``csum`` (N+1,) f32 are device arrays;
-    every other argument is static.  Returns ``(slab, steps, workers,
-    starts, sizes, clocks, counts)`` with the slab aliased in place.
-    ``claim_schedule`` is the host wrapper around this call.
-    """
+def _protocol_outputs(slab, csum, *, technique: str, N: int, P: int,
+                      chunk: int, max_chunk: Optional[int], S: int,
+                      i_slot: int, lp_slot: int, interpret: bool):
+    """The protocol kernel's ``pallas_call``: ``(slab, steps, workers,
+    starts, sizes, clocks, counts)``, the slab aliased in place."""
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -151,6 +143,36 @@ def protocol_call(slab, csum, *, technique: str, N: int, P: int,
         interpret=interpret,
         name="dls_protocol",
     )(slab, csum)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "technique", "N", "P", "chunk", "max_chunk", "S", "i_slot", "lp_slot",
+    "interpret"))
+def protocol_call(slab, csum, *, technique: str, N: int, P: int,
+                  chunk: int, max_chunk: Optional[int], S: int,
+                  i_slot: int, lp_slot: int, interpret: bool):
+    """The protocol kernel, jitted: arrays in and out.
+
+    ``slab`` (cap,) int32 and ``csum`` (N+1,) f32 are device arrays;
+    every other argument is static.  Returns ``(slab, packed)``: the slab
+    aliased in place, and the schedule as one int32 vector of length
+    ``4*S + 2*P``, packed in the same module so that the host reads it
+    back in one transfer::
+
+        steps (S) | workers (S) | starts (S) | sizes (S) | counts (P) | clocks (P)
+
+    ``clocks`` holds the float32 clocks' bits (``bitcast_convert_type``,
+    exact).  ``claim_schedule`` is the host wrapper around this call.
+    """
+    import jax.numpy as jnp
+
+    slab, steps, workers, starts, sizes, clocks, counts = _protocol_outputs(
+        slab, csum, technique=technique, N=N, P=P, chunk=chunk,
+        max_chunk=max_chunk, S=S, i_slot=i_slot, lp_slot=lp_slot,
+        interpret=interpret)
+    return slab, jnp.concatenate([
+        steps, workers, starts, sizes, counts,
+        jax.lax.bitcast_convert_type(clocks, jnp.int32)])
 
 
 @dataclasses.dataclass
@@ -228,6 +250,11 @@ def claim_schedule(
     protocol (fresh zeros when None -- note nonzero counters resume a
     partially-drained loop, exactly like the host runtime).  Runs under
     the Pallas interpreter on CPU (``kernels.resolve_interpret``).
+
+    The schedule comes back in one device-to-host transfer, the packed
+    vector of ``protocol_call``; every field is a slice of that one host
+    buffer (``clocks`` viewed back as float32), and the slab stays on the
+    device.
     """
     import jax.numpy as jnp
 
@@ -256,21 +283,23 @@ def claim_schedule(
                              f"for slab of capacity {cap}")
 
         with tracing.launch("claim.launch", protocol_call):
-            new_slab, steps, workers, starts, sizes, clocks, counts = \
-                protocol_call(slab, csum, technique=technique, N=N, P=P,
-                              chunk=chunk, max_chunk=max_chunk, S=S,
-                              i_slot=i_slot, lp_slot=lp_slot,
-                              interpret=interpret)
+            new_slab, packed = protocol_call(
+                slab, csum, technique=technique, N=N, P=P, chunk=chunk,
+                max_chunk=max_chunk, S=S, i_slot=i_slot, lp_slot=lp_slot,
+                interpret=interpret)
 
-        with tracing.span("claim.readback"):
-            workers = np.asarray(workers)
+        with tracing.span("claim.readback") as readback:
+            host = np.asarray(packed)  # the one device-to-host transfer
+            steps, workers, starts, sizes = host[:4 * S].reshape(4, S)
+            counts, clocks = host[4 * S:].reshape(2, P)
             n = int((workers >= 0).sum())  # granted rows form a prefix
             sched = DeviceSchedule(
                 technique=technique, N=N, P=P, chunk=chunk,
-                steps=np.asarray(steps)[:n], workers=workers[:n],
-                starts=np.asarray(starts)[:n], sizes=np.asarray(sizes)[:n],
-                counts=np.asarray(counts, np.int64),
-                clocks=np.asarray(clocks), slab=new_slab)
+                steps=steps[:n], workers=workers[:n], starts=starts[:n],
+                sizes=sizes[:n], counts=counts.astype(np.int64),
+                clocks=clocks.view(np.float32), slab=new_slab)
+            if tracing.enabled():
+                readback.set_metadata(arrays=1, bytes=packed.nbytes)
         if tracing.enabled():
             claim.set_metadata(steps=S, claims=n)
     return sched

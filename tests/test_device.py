@@ -7,6 +7,8 @@ index* (golden parity), claims partition [0, N) exactly (conservation),
 and a device-made session report round-trips through the ordinary
 replay plane (capture -> calibrate -> simulate -> gantt) unchanged.
 """
+import functools
+
 import numpy as np
 import pytest
 
@@ -104,6 +106,50 @@ def test_claim_schedule_resumes_from_nonzero_counters():
     assert np.array_equal(rest.sizes, full.sizes[k:])
     assert np.array_equal(rest.starts, full.starts[k:])
     assert np.array_equal(rest.steps, full.steps[k:])
+
+
+@pytest.mark.parametrize("resumed", [False, True], ids=["fresh", "resumed"])
+@pytest.mark.parametrize("technique", ["gss", "fac2", "tss", "ss"])
+def test_claim_schedule_reads_back_what_the_kernel_wrote(technique, resumed):
+    """One packed vector comes back, and every schedule field sliced from
+    it equals the kernel's own output read back array by array."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core.chunk_calculus import max_steps_bound
+    from repro.device import persistent
+
+    N, P = 150, 3
+    costs = np.linspace(1.0, 3.0, N) ** 2
+    slab = jnp.zeros(2, jnp.int32)
+    if resumed:  # the first 4 claims already happened
+        full = claim_schedule(technique, N, P)
+        slab = slab.at[0].set(4).at[1].set(int(full.starts[4]))
+    S = int(max_steps_bound(host_spec(technique, N, P)))
+    csum = np.zeros(N + 1, np.float32)
+    np.cumsum(costs, out=csum[1:])
+    kw = dict(technique=technique, N=N, P=P, chunk=1, max_chunk=None, S=S,
+              i_slot=0, lp_slot=1, interpret=True)
+
+    out = persistent.protocol_call(slab, jnp.asarray(csum), **kw)
+    assert len(out) == 2
+    assert out[1].dtype == jnp.int32 and out[1].shape == (4 * S + 2 * P,)
+
+    _, steps, workers, starts, sizes, clocks, counts = (
+        np.asarray(a) for a in jax.jit(functools.partial(
+            persistent._protocol_outputs, **kw))(slab, jnp.asarray(csum)))
+    n = int((workers >= 0).sum())
+    want = dict(steps=steps[:n], workers=workers[:n], starts=starts[:n],
+                sizes=sizes[:n], counts=counts.astype(np.int64))
+    sched = claim_schedule(technique, N, P, costs=costs, slab=slab)
+    assert sched.n_steps == n > 0
+    for name, value in want.items():
+        got = getattr(sched, name)
+        assert got.dtype == value.dtype and np.array_equal(got, value), name
+    assert sched.clocks.dtype == np.float32
+    assert sched.clocks.tobytes() == clocks.tobytes()  # bit for bit
+    assert len(np.unique(sched.clocks)) > 1  # the costs are not uniform
+    assert int(np.asarray(sched.slab)[1]) >= N
 
 
 def test_schedule_timeline_consistency():

@@ -13,6 +13,7 @@ keeps it until it exits.
 """
 import functools
 import os
+import re
 
 import pytest
 
@@ -72,6 +73,9 @@ def test_protocol_kernel_compiles(one_chip, technique):
     text = _compile(one_chip, fn, ((256,), jnp.int32),
                     ((N_TILES + 1,), jnp.float32))
     assert "%dls_protocol" in text  # the kernel's name in the device trace
+    # two outputs: the slab, and the schedule packed for one read-back
+    header = re.sub(r"\{[^{}]*\}", "", text.splitlines()[0])  # no layouts
+    assert f"->(s32[256], s32[{4 * S + 2 * P}])" in header
 
 
 def test_static_mandelbrot_compiles(one_chip):

@@ -105,6 +105,10 @@ def test_claim_counters_are_the_grants_and_the_loop_length(tmp_path):
     S = int(max_steps_bound(host_spec("gss", 81, P)))
     assert claim["stats"] == {"claims": sched.n_steps, "steps": S}
     assert (sched.n_steps, S) == (17, 100)
+    # the schedule comes back as one packed int32 vector of 4*S + 2*P
+    (readback,) = [s for s in spans if s["name"] == "repro.claim.readback"]
+    assert _parent(readback, spans) == "repro.claim"
+    assert readback["stats"] == {"arrays": 1, "bytes": 4 * (4 * S + 2 * P)}
 
 
 def test_launch_counts_a_compile_on_the_first_call_only(tmp_path):
@@ -131,6 +135,10 @@ def test_span_count_per_drain_does_not_grow_with_the_claims(tmp_path):
     assert [s["name"] for s in spans_few] == [s["name"] for s in spans_many]
     (claim,) = [s for s in spans_many if s["name"] == "repro.claim"]
     assert claim["stats"] == {"claims": 1296, "steps": 1296}
+    (readback,) = [s for s in spans_many
+                   if s["name"] == "repro.claim.readback"]
+    assert readback["stats"] == {"arrays": 1,
+                                 "bytes": 4 * (4 * 1296 + 2 * P)}
 
 
 class _Jitted:
