@@ -25,6 +25,15 @@ class ModelConfig:
     top_k: int = 0
     n_shared_experts: int = 0  # always-on experts (llama4-style)
     capacity_factor: float = 1.25
+    # DeepSeek-V3's group-limited router (``layers.moe_route``): sigmoid
+    # scores; the experts fall in ``n_group`` equal groups, each group is
+    # scored by the sum of its top 2 scores, and the top-k experts are
+    # chosen from the best ``topk_group`` groups; the chosen scores are
+    # normalized to sum 1 and scaled by ``routed_scaling_factor``.
+    # ``moe_block`` (softmax, capacity) ignores them.
+    n_group: int = 1
+    topk_group: int = 1
+    routed_scaling_factor: float = 1.0
     # --- attention ---
     window: Optional[int] = None  # sliding-window attention (SWA)
     rope_theta: float = 10_000.0

@@ -9,6 +9,10 @@
 self-scheduled* variants (``*_persistent``): a fixed worker grid claiming
 variable-sized tile chunks through the device-window protocol of
 ``repro.device`` instead of a static grid -- DESIGN.md Sec. 14.
+
+  moe_experts     -- persistent only: one chip's routed experts of a MoE
+                     layer (DeepSeek-V3), (expert, row-block) tiles whose
+                     costs come from the router, weights streamed from HBM
 """
 import jax
 
@@ -37,5 +41,6 @@ from .flash_attention.ops import attention_oracle, flash_attention  # noqa: F401
 from .flash_attention.persistent import flash_attention_persistent  # noqa: F401,E402
 from .mandelbrot.ops import mandelbrot, mandelbrot_ref  # noqa: F401,E402
 from .mandelbrot.persistent import mandelbrot_persistent  # noqa: F401,E402
+from .moe_experts.persistent import moe_experts_persistent  # noqa: F401,E402
 from .spin_image.ops import spin_images, spin_images_oracle  # noqa: F401,E402
 from .ssd_scan.ops import ssd_scan, ssd_scan_oracle  # noqa: F401,E402

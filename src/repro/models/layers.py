@@ -554,3 +554,82 @@ def moe_block(params, x, cfg, *, ctx: ShardCtx = NO_SHARD):
         # (16x per-device FLOPs; see EXPERIMENTS.md §Perf P5).
         out = out + mlp_block(params["shared"], x, ctx=ctx)
     return cs(out, "batch", None, None, ctx=ctx)
+
+
+# ---------------------------------------------------------------------------
+# DeepSeek-V3 routed experts: one chip's share under expert parallelism
+# ---------------------------------------------------------------------------
+
+
+@_ft.partial(jax.jit, static_argnames=("cfg",))
+def moe_route(x, router, bias, cfg):
+    """DeepSeek-V3's group-limited router: ``(expert_ids, expert_w)``,
+    each (T, top_k), over all ``cfg.n_experts`` experts.
+
+    x (T, d) and router (d, n_experts) in the config's dtype, the matmul
+    accumulated in float32; ``bias`` (n_experts,) is the per-expert
+    correction bias (``e_score_correction_bias``), which steers the
+    choice and not the weights.  Scores are sigmoids; each of the
+    ``n_group`` groups is scored by the sum of its top 2 biased scores,
+    the top-k experts are chosen among the best ``topk_group`` groups, and
+    their unbiased scores are normalized and scaled by
+    ``routed_scaling_factor``.
+    """
+    T = x.shape[0]
+    E, G = cfg.n_experts, cfg.n_group
+    scores = jax.nn.sigmoid(
+        jnp.dot(x, router, preferred_element_type=jnp.float32))
+    choice = scores + bias
+    # each group's two best scores by two reductions, not a sort
+    grouped = choice.reshape(T, G, E // G)
+    first = jnp.argmax(grouped, axis=-1)[..., None]
+    second = jnp.where(jnp.arange(E // G) == first, -jnp.inf, grouped)
+    top2 = grouped.max(-1) + second.max(-1)
+    groups = jax.lax.top_k(top2, cfg.topk_group)[1]
+    keep = (groups[:, :, None] == jnp.arange(G)).any(1)
+    masked = jnp.where(jnp.repeat(keep, E // G, axis=1), choice, -jnp.inf)
+    picked, ids = jax.lax.top_k(masked, cfg.top_k)
+    # the unbiased scores of the chosen: the bias comes off by a compare
+    # with the small bias table, where a gather of T*top_k scores from
+    # the (T, n_experts) table costs ms on the chip
+    w = picked - jnp.where(ids[..., None] == jnp.arange(E), bias, 0.0).sum(-1)
+    w = w / (w.sum(-1, keepdims=True) + 1e-20) * cfg.routed_scaling_factor
+    return ids.astype(jnp.int32), w
+
+
+def moe_held_init(key, cfg, held, dtype):
+    """One chip's share of a DeepSeek-V3 MoE layer: the router over all
+    experts with its (zero) correction bias, the weights of the ``held``
+    experts, and the shared expert."""
+    d, ff, E = cfg.d_model, cfg.d_ff, len(held)
+    ks = jax.random.split(key, 5)
+    return {
+        "router": dense_init(ks[0], (d, cfg.n_experts), dtype=dtype),
+        "bias": jnp.zeros((cfg.n_experts,), jnp.float32),
+        "wg": dense_init(ks[1], (E, d, ff), scale=d ** -0.5, dtype=dtype),
+        "wu": dense_init(ks[2], (E, d, ff), scale=d ** -0.5, dtype=dtype),
+        "wd": dense_init(ks[3], (E, ff, d), scale=ff ** -0.5, dtype=dtype),
+        "shared": mlp_init(ks[4], d, ff * cfg.n_shared_experts, dtype),
+    }
+
+
+def moe_held_block(params, x, cfg, *, held, technique: str = "gss",
+                   workers: int = 8, blk: int = 256):
+    """A DeepSeek-V3 MoE layer on a chip that holds the experts ``held``.
+
+    Routes every token over all experts (``moe_route``), computes the held
+    experts' part dropless through the self-scheduled expert kernel
+    (``kernels.moe_experts_persistent``: (expert, row-block) tiles claimed
+    through the device protocol), and adds the shared expert.  What the
+    experts held elsewhere add is left out: that partial result is what
+    this chip gives the layer.  x (B, T, d).
+    """
+    from repro.kernels import moe_experts_persistent
+
+    B, T, d = x.shape
+    flat = x.reshape(B * T, d)
+    ids, w = moe_route(flat, params["router"], params["bias"], cfg)
+    y, _ = moe_experts_persistent(
+        flat, params["wg"], params["wu"], params["wd"], ids, w, held=held,
+        technique=technique, workers=workers, blk=blk)
+    return (y + mlp_block(params["shared"], flat)).reshape(B, T, d)

@@ -21,6 +21,8 @@ N_TILES, P = 81, 8            # the paper's 1152^2 Mandelbrot in 128^2 tiles
 WIDTH, CT = 1152, 1000
 VARLEN_B, T = 3, 2048         # the largest tinyllama varlen batch in VMEM
 HEADS, KV_HEADS, HEAD_DIM = 32, 4, 64
+# DeepSeek-V3's routed experts: one chip's 8, 65536 tokens a drain
+MOE_D, MOE_F, MOE_E, MOE_T, MOE_BLK = 7168, 2048, 8, 65536, 256
 
 
 @pytest.fixture(scope="module")
@@ -135,6 +137,35 @@ def test_persistent_flash_attention_compiles(one_chip, B, fits):
                         ((B, KV_HEADS, T, HEAD_DIM), jnp.bfloat16),
                         ((B, KV_HEADS, T, HEAD_DIM), jnp.bfloat16))
         assert "%attention_persistent" in text
+
+
+def test_persistent_moe_experts_compile(one_chip):
+    """The streaming expert kernel at DeepSeek-V3's widths, with its
+    gather and combine; what it asks of VMEM fits the chip's 128 MiB
+    (one expert's weights, 88 MB, would not fit twice)."""
+    import jax.numpy as jnp
+
+    from repro.kernels.moe_experts.persistent import (persistent_call,
+                                                      vmem_limit)
+
+    limit = vmem_limit(MOE_BLK, MOE_D, MOE_F, jnp.bfloat16)
+    assert limit <= 100 << 20
+    C, M = 32, 18432  # claims per worker; rows of 72 live tiles
+    fn = functools.partial(persistent_call, M=M, blk=MOE_BLK,
+                           interpret=False)
+    i32, bf16 = jnp.int32, jnp.bfloat16
+    text = _compile(one_chip, fn, ((P,), i32), ((P, C), i32), ((P, C), i32),
+                    ((MOE_E,), i32), ((MOE_E, MOE_T), i32),
+                    ((MOE_E, MOE_T), jnp.float32), ((MOE_E, MOE_T), i32),
+                    ((MOE_E, MOE_T // 256 + 1), i32),
+                    ((MOE_T, MOE_D), bf16), ((MOE_E, MOE_D, MOE_F), bf16),
+                    ((MOE_E, MOE_D, MOE_F), bf16),
+                    ((MOE_E, MOE_F, MOE_D), bf16))
+    assert "%moe_experts_persistent" in text and "%moe_combine" in text
+    # the expert kernel writes only the rows gathered, not a worst case
+    assert re.search(rf"f32\[{M + 32},{MOE_D}\][^ ]* custom-call", text)
+    # no scatter: its loops cost ms a drain on the chip
+    assert " scatter(" not in text
 
 
 def test_spin_images_compile(one_chip):

@@ -16,7 +16,8 @@ from repro import dls, tracing
 from repro.core.chunk_calculus import max_steps_bound
 from repro.device import host_spec
 from repro.device.persistent import claim_schedule
-from repro.kernels import flash_attention_persistent, mandelbrot_persistent
+from repro.kernels import (flash_attention_persistent, mandelbrot_persistent,
+                           moe_experts_persistent)
 
 ROOT = Path(__file__).resolve().parent.parent
 P, TILE = 8, 8
@@ -30,6 +31,9 @@ NESTING = {
     "mandelbrot": dict(CLAIM, **{"repro.session.open": None,
                                  "repro.report": None}),
     "attention": dict(CLAIM, **{"repro.tile_costs": None}),
+    "moe": dict(CLAIM, **{"repro.route": None,
+                          "repro.route.readback": "repro.route",
+                          "repro.tile_costs": None}),
 }
 
 
@@ -87,8 +91,26 @@ def _attention_drain():
     return sched
 
 
+def _moe_drain():
+    """One chip's 4 of 16 experts, top 2 of 16: expert 1 takes every
+    token, expert 2 none."""
+    T, d, F, E = 40, 128, 128, 4
+    ks = jax.random.split(jax.random.key(1), 4)
+    x = jax.random.normal(ks[0], (T, d))
+    wg, wu = (jax.random.normal(k, (E, d, F)) for k in ks[1:3])
+    wd = jax.random.normal(ks[3], (E, F, d))
+    ids = np.stack([np.full(T, 1), 4 + np.arange(T) % 12], 1)
+    ids[:7, 1] = 0  # 7 rows for expert 0, 0 for 2, 3 for 3
+    ids[7:10, 1] = 3
+    y, sched = moe_experts_persistent(x, wg, wu, wd, ids.astype(np.int32),
+                                      np.full((T, 2), 0.5, np.float32),
+                                      held=range(4), workers=P, blk=8)
+    jax.block_until_ready(y)
+    return sched
+
+
 DRAINS = {"mandelbrot": lambda: _mandel_drain(81, "gss"),
-          "attention": _attention_drain}
+          "attention": _attention_drain, "moe": _moe_drain}
 
 
 @pytest.mark.parametrize("path", sorted(DRAINS))
@@ -109,6 +131,15 @@ def test_claim_counters_are_the_grants_and_the_loop_length(tmp_path):
     (readback,) = [s for s in spans if s["name"] == "repro.claim.readback"]
     assert _parent(readback, spans) == "repro.claim"
     assert readback["stats"] == {"arrays": 1, "bytes": 4 * (4 * S + 2 * P)}
+
+
+def test_route_readback_counts_the_held_pairs_once(tmp_path):
+    _, spans = _traced(tmp_path, _moe_drain)
+    (readback,) = [s for s in spans if s["name"] == "repro.route.readback"]
+    # loads 7, 40, 0, 3 in blocks of 8: 1 + 5 + 0 + 1 live tiles; one
+    # int32 a held expert comes back
+    assert readback["stats"] == {"bytes": 4 * 4, "pairs": 50,
+                                 "max_load": 40, "live_tiles": 7}
 
 
 def test_launch_counts_a_compile_on_the_first_call_only(tmp_path):
