@@ -17,7 +17,9 @@ on-device" item (see DESIGN.md Sec. 14):
                     index-for-index equal to ``core.chunk_calculus``.
   persistent.py     the protocol kernel: one persistent launch walks
                     Step 1-3 of the paper against the aliased slab and
-                    emits the full (step, worker, start, size) schedule.
+                    emits the full (step, worker, start, size) schedule,
+                    with the per-worker tables the compute kernels take
+                    built on the device beside it.
   runtime.py        ``DeviceRuntime`` -- ``OneSidedRuntime`` over a
                     ``DeviceWindow`` (``dls.loop(runtime="device")``).
   executor.py       ``executor="device"``: run the in-kernel protocol,
@@ -28,6 +30,7 @@ from .chunk_calculus import (  # noqa: F401
     DEVICE_TECHNIQUES,
     chunk_size_device,
     host_spec,
+    plan_claims,
 )
 from .executor import execute_device  # noqa: F401
 from .persistent import DeviceSchedule, claim_schedule, schedule_timeline  # noqa: F401

@@ -35,6 +35,7 @@ need live telemetry and stay host-side.
 """
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax.numpy as jnp
@@ -178,6 +179,22 @@ def max_steps_device(technique: str, N: int, P: int, chunk: int = 1,
     from repro.core.chunk_calculus import max_steps_bound
 
     return int(max_steps_bound(host_spec(technique, N, P, chunk, max_chunk)))
+
+
+@functools.lru_cache(maxsize=256)
+def plan_claims(technique: str, N: int, P: int, chunk: int = 1,
+                max_chunk: Optional[int] = None) -> int:
+    """Claims of the technique's closed-form plan over a fresh loop.
+
+    Chunk sizes are closed forms in the step index, so the count does not
+    depend on the cost model (only the choice of worker reads costs), and
+    a loop resumed from counters the protocol left behind grants the rest
+    of the same plan.  No worker can take more claims than this: it is the
+    static width of the per-worker claim tables (``protocol_call``).
+    """
+    from repro.core.chunk_calculus import scheduling_steps
+
+    return scheduling_steps(host_spec(technique, N, P, chunk, max_chunk))
 
 
 def plan_device(technique: str, N: int, P: int, chunk: int = 1,

@@ -28,7 +28,6 @@ Chunk-sequence parity with the host ``plan()`` is pinned index-for-index
 """
 from __future__ import annotations
 
-import dataclasses
 import functools
 from typing import Optional
 
@@ -38,7 +37,7 @@ import numpy as np
 from repro import tracing
 from repro.core.chunk_calculus import max_steps_bound
 
-from .chunk_calculus import chunk_size_device, host_spec
+from .chunk_calculus import chunk_size_device, host_spec, plan_claims
 
 
 def _protocol_kernel(
@@ -145,6 +144,36 @@ def _protocol_outputs(slab, csum, *, technique: str, N: int, P: int,
     )(slab, csum)
 
 
+def _worker_tables(workers, starts, sizes, counts, *, P: int, C: int):
+    """The per-worker claim tables of a schedule, built on the device.
+
+    Returns ``(nclaims (P,), starts (P, C), sizes (P, C))`` int32: worker
+    ``w``'s first ``nclaims[w]`` entries are its grants in protocol order,
+    the rest zero-sized.  Each granted row's place in the flat table is
+    ``w * C + rank``, its rank among ``w``'s rows a cumulative sum of the
+    one-hot worker column; each empty place gets a zero-sized filler.  A
+    sort on the place puts all of them in table order: no scatter, and no
+    gather.  Rows that have no place (no grant, or past ``C``) and the
+    fillers of taken places sort to the end, which is cut off.
+    """
+    import jax.numpy as jnp
+
+    S = workers.shape[0]
+    mine = workers[:, None] == jnp.arange(P, dtype=jnp.int32)  # (S, P)
+    rank = jnp.sum(jnp.where(mine, jnp.cumsum(mine, axis=0), 0), axis=1) - 1
+    off = P * C + jnp.arange(S, dtype=jnp.int32)
+    place = jnp.where((workers >= 0) & (rank < C), workers * C + rank, off)
+    slot = jnp.arange(P * C, dtype=jnp.int32).reshape(P, C)
+    fill = jnp.where(slot % C >= counts[:, None], slot,
+                     P * C + S + slot).reshape(-1)
+    zeros = jnp.zeros(P * C, jnp.int32)
+    _, t_starts, t_sizes = jax.lax.sort(
+        (jnp.concatenate([place, fill]), jnp.concatenate([starts, zeros]),
+         jnp.concatenate([sizes, zeros])), num_keys=1)
+    return (jnp.minimum(counts, C), t_starts[:P * C].reshape(P, C),
+            t_sizes[:P * C].reshape(P, C))
+
+
 @functools.partial(jax.jit, static_argnames=(
     "technique", "N", "P", "chunk", "max_chunk", "S", "i_slot", "lp_slot",
     "interpret"))
@@ -154,15 +183,23 @@ def protocol_call(slab, csum, *, technique: str, N: int, P: int,
     """The protocol kernel, jitted: arrays in and out.
 
     ``slab`` (cap,) int32 and ``csum`` (N+1,) f32 are device arrays;
-    every other argument is static.  Returns ``(slab, packed)``: the slab
-    aliased in place, and the schedule as one int32 vector of length
-    ``4*S + 2*P``, packed in the same module so that the host reads it
-    back in one transfer::
+    every other argument is static.  Returns ``(slab, packed, tables)``:
 
-        steps (S) | workers (S) | starts (S) | sizes (S) | counts (P) | clocks (P)
+    * the slab, aliased in place;
+    * the schedule as one int32 vector of length ``4*S + 2*P``, packed so
+      that the host reads it back in one transfer::
 
-    ``clocks`` holds the float32 clocks' bits (``bitcast_convert_type``,
-    exact).  ``claim_schedule`` is the host wrapper around this call.
+          steps (S) | workers (S) | starts (S) | sizes (S) | counts (P) | clocks (P)
+
+      ``clocks`` holds the float32 clocks' bits (``bitcast_convert_type``,
+      exact);
+    * the per-worker claim tables the compute kernels take,
+      ``(nclaims (P,), starts (P, C), sizes (P, C))`` (``_worker_tables``),
+      ``C = min(plan_claims(...), S)``.
+
+    The tables are built in this module, so the schedule reaches the
+    compute kernel without passing through the host.  ``claim_schedule``
+    is the host wrapper around this call.
     """
     import jax.numpy as jnp
 
@@ -170,32 +207,83 @@ def protocol_call(slab, csum, *, technique: str, N: int, P: int,
         slab, csum, technique=technique, N=N, P=P, chunk=chunk,
         max_chunk=max_chunk, S=S, i_slot=i_slot, lp_slot=lp_slot,
         interpret=interpret)
-    return slab, jnp.concatenate([
+    C = min(plan_claims(technique, N, P, chunk, max_chunk), S)
+    packed = jnp.concatenate([
         steps, workers, starts, sizes, counts,
         jax.lax.bitcast_convert_type(clocks, jnp.int32)])
+    return slab, packed, _worker_tables(workers, starts, sizes, counts,
+                                        P=P, C=C)
 
 
-@dataclasses.dataclass
+def _host_view(name: str, doc: str):
+    return property(lambda self: self._host[name], doc=doc)
+
+
 class DeviceSchedule:
-    """A fully-materialized device-made schedule (+ the mutated slab).
+    """A device-made schedule: its tables on the device, its rows on demand.
 
-    ``steps/workers/starts/sizes`` are the granted claims in protocol
-    order; ``counts``/``clocks`` are the per-block claim counts and
-    modeled busy clocks the report plane surfaces; ``slab`` is the
-    window slab *after* the kernel ran (adopt it back into the window).
+    ``tables`` are the per-worker claim tables the compute kernels take
+    (``protocol_call``), and ``slab`` the window slab *after* the kernel
+    ran (adopt it back into the window); both stay on the device.
+
+    The host views -- ``steps/workers/starts/sizes``, the granted claims
+    in protocol order, and the per-block claim counts and modeled busy
+    clocks ``counts``/``clocks`` the report plane surfaces -- are slices
+    of the packed vector, read back (``repro.claim.readback``) on first
+    access and kept.  The copy starts when the claim kernel is launched,
+    so a read after the compute kernel finds it landed.
     """
 
-    technique: str
-    N: int
-    P: int
-    chunk: int
-    steps: np.ndarray    # (n_steps,) int32
-    workers: np.ndarray  # (n_steps,) int32
-    starts: np.ndarray   # (n_steps,) int32
-    sizes: np.ndarray    # (n_steps,) int32
-    counts: np.ndarray   # (P,) int64 per-worker claim counts
-    clocks: np.ndarray   # (P,) float modeled busy time
-    slab: object         # jnp (cap,) int32 -- final window counters
+    def __init__(self, technique: str, N: int, P: int, chunk: int, *,
+                 packed, tables, slab):
+        self.technique, self.N, self.P, self.chunk = technique, N, P, chunk
+        self.packed = packed  # jnp (4*S + 2*P,) int32
+        self.tables = tables  # jnp (P,), (P, C), (P, C) int32
+        self.slab = slab      # jnp (cap,) int32 -- final window counters
+        #: whether a compute kernel was handed ``tables`` (``launch_tables``)
+        self.launched = False
+
+    def launch_tables(self):
+        """``tables``, for a compute kernel about to launch on them.
+
+        The span ``repro.tables`` marks the hand-over, with the tables'
+        width as its counter ``width``: the protocol module built them on
+        the device, so the host builds and uploads nothing here.  A host
+        view first read after this counts ``after_launch``.
+        """
+        with tracing.span("tables") as span:
+            if tracing.enabled():
+                span.set_metadata(width=int(self.tables[1].shape[1]))
+            self.launched = True
+            return self.tables
+
+    @functools.cached_property
+    def _host(self) -> dict:
+        P, C = self.P, self.tables[1].shape[1]
+        S = (self.packed.shape[0] - 2 * P) // 4
+        with tracing.span("claim.readback") as readback:
+            host = np.asarray(self.packed)  # the one device-to-host transfer
+            if tracing.enabled():
+                readback.set_metadata(arrays=1, bytes=self.packed.nbytes,
+                                      after_launch=int(self.launched))
+        steps, workers, starts, sizes = host[:4 * S].reshape(4, S)
+        counts, clocks = host[4 * S:].reshape(2, P)
+        if counts.max(initial=0) > C:
+            raise RuntimeError(
+                f"a worker took {counts.max()} claims, more than the "
+                f"{C} of the plan: the window's counters were not left by "
+                "this loop's protocol, and its tables dropped claims")
+        n = int((workers >= 0).sum())  # granted rows form a prefix
+        return dict(steps=steps[:n], workers=workers[:n], starts=starts[:n],
+                    sizes=sizes[:n], counts=counts.astype(np.int64),
+                    clocks=clocks.view(np.float32))
+
+    steps = _host_view("steps", "(n_steps,) int32 protocol step of each grant")
+    workers = _host_view("workers", "(n_steps,) int32 granted worker")
+    starts = _host_view("starts", "(n_steps,) int32 first iteration")
+    sizes = _host_view("sizes", "(n_steps,) int32 iterations")
+    counts = _host_view("counts", "(P,) int64 per-worker claim counts")
+    clocks = _host_view("clocks", "(P,) float32 modeled busy time")
 
     @property
     def n_steps(self) -> int:
@@ -209,23 +297,6 @@ class DeviceSchedule:
     def makespan(self) -> float:
         """Modeled finish time of the busiest worker."""
         return float(self.clocks.max()) if len(self.clocks) else 0.0
-
-    def worker_lists(self):
-        """Padded per-worker claim tables for the compute kernels.
-
-        Returns ``(nclaims (P,), starts (P, C), sizes (P, C))`` int32,
-        ``C = max(claims per worker, 1)``; padding rows are zero-sized.
-        """
-        C = max(int(self.counts.max()) if len(self.counts) else 0, 1)
-        nclaims = np.zeros(self.P, np.int32)
-        starts = np.zeros((self.P, C), np.int32)
-        sizes = np.zeros((self.P, C), np.int32)
-        for w, st, sz in zip(self.workers, self.starts, self.sizes):
-            c = nclaims[w]
-            starts[w, c] = st
-            sizes[w, c] = sz
-            nclaims[w] = c + 1
-        return nclaims, starts, sizes
 
 
 def claim_schedule(
@@ -251,10 +322,9 @@ def claim_schedule(
     partially-drained loop, exactly like the host runtime).  Runs under
     the Pallas interpreter on CPU (``kernels.resolve_interpret``).
 
-    The schedule comes back in one device-to-host transfer, the packed
-    vector of ``protocol_call``; every field is a slice of that one host
-    buffer (``clocks`` viewed back as float32), and the slab stays on the
-    device.
+    Returns without waiting for the device: the claim tables stay there
+    for the compute kernel, and the packed schedule's copy to the host is
+    started, to be read when a host view is first asked for.
     """
     import jax.numpy as jnp
 
@@ -283,26 +353,18 @@ def claim_schedule(
                              f"for slab of capacity {cap}")
 
         with tracing.launch("claim.launch", protocol_call):
-            new_slab, packed = protocol_call(
+            new_slab, packed, tables = protocol_call(
                 slab, csum, technique=technique, N=N, P=P, chunk=chunk,
                 max_chunk=max_chunk, S=S, i_slot=i_slot, lp_slot=lp_slot,
                 interpret=interpret)
-
-        with tracing.span("claim.readback") as readback:
-            host = np.asarray(packed)  # the one device-to-host transfer
-            steps, workers, starts, sizes = host[:4 * S].reshape(4, S)
-            counts, clocks = host[4 * S:].reshape(2, P)
-            n = int((workers >= 0).sum())  # granted rows form a prefix
-            sched = DeviceSchedule(
-                technique=technique, N=N, P=P, chunk=chunk,
-                steps=steps[:n], workers=workers[:n], starts=starts[:n],
-                sizes=sizes[:n], counts=counts.astype(np.int64),
-                clocks=clocks.view(np.float32), slab=new_slab)
-            if tracing.enabled():
-                readback.set_metadata(arrays=1, bytes=packed.nbytes)
+        packed.copy_to_host_async()
         if tracing.enabled():
-            claim.set_metadata(steps=S, claims=n)
-    return sched
+            # the tables' width is the plan's claim count: exact for a
+            # loop claimed from its start, with no read-back (a resumed
+            # loop grants fewer, which ``n_steps`` gives once read)
+            claim.set_metadata(steps=S, claims=int(tables[1].shape[1]))
+    return DeviceSchedule(technique, N, P, chunk, packed=packed,
+                          tables=tables, slab=new_slab)
 
 
 def schedule_timeline(schedule: DeviceSchedule, costs=None):

@@ -247,11 +247,9 @@ def flash_attention_persistent(
         raise ValueError(
             f"schedule is for (N={schedule.N}, P={schedule.P}), "
             f"this tile space needs (N={N}, P={workers})")
-    with tracing.span("tables"):
-        tables = [jnp.asarray(t) for t in (*schedule.worker_lists(),
-                                           lengths)]
+    tables = schedule.launch_tables()
     with tracing.launch("compute.launch", persistent_call):
         out = persistent_call(
-            *tables, q, k, v, causal=causal, scale=float(scale),
+            *tables, lengths, q, k, v, causal=causal, scale=float(scale),
             blk_q=blk_q, blk_k=blk_k, interpret=interpret)
     return out, schedule

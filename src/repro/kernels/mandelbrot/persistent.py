@@ -82,7 +82,7 @@ def persistent_call(nclaims, starts, sizes, *, width: int, height: int,
     """The persistent kernel's ``pallas_call``: jittable, arrays in and out.
 
     ``nclaims (W,)``, ``starts``/``sizes (W, C)`` int32 are the per-worker
-    claim tables (``DeviceSchedule.worker_lists``); they ride in SMEM as
+    claim tables (``DeviceSchedule.tables``); they ride in SMEM as
     scalar-prefetch operands, because each program reads them one entry
     at a time.  Returns the padded ``(gh*block_h, gw*block_w)`` image.
     """
@@ -153,8 +153,7 @@ def mandelbrot_persistent(
         raise ValueError(
             f"schedule is for (N={schedule.N}, P={schedule.P}), "
             f"this grid needs (N={N}, P={workers})")
-    with tracing.span("tables"):
-        tables = [jnp.asarray(t) for t in schedule.worker_lists()]
+    tables = schedule.launch_tables()
     with tracing.launch("compute.launch", persistent_call):
         out = persistent_call(
             *tables, width=width, height=height, ct=ct, xlim=tuple(xlim),

@@ -415,11 +415,6 @@ def persistent_call(nclaims, starts, sizes, loads, tok, w, loc, bounds, x,
     )(bounds.reshape(-1), offs, loc, ys)[:T]
 
 
-def _bucket(n: int) -> int:
-    """Claim-table width: a power of 2, at least 8."""
-    return max(8, 1 << (n - 1).bit_length())
-
-
 def moe_experts_persistent(x, w_gate, w_up, w_down, expert_ids, expert_w, *,
                            held, technique: str = "gss", workers: int = 8,
                            blk: int = 256, interpret: bool | None = None):
@@ -456,11 +451,7 @@ def moe_experts_persistent(x, w_gate, w_up, w_down, expert_ids, expert_w, *,
         costs = expert_tile_costs(loads, T, blk)
     schedule = claim_schedule(technique, E * R, workers, costs=costs,
                               interpret=interpret)
-    with tracing.span("tables"):
-        nclaims, starts, sizes = schedule.worker_lists()
-        pad = ((0, 0), (0, _bucket(starts.shape[1]) - starts.shape[1]))
-        tables = [jnp.asarray(t) for t in (nclaims, np.pad(starts, pad),
-                                           np.pad(sizes, pad))]
+    tables = schedule.launch_tables()
     with tracing.launch("compute.launch", persistent_call):
         y = persistent_call(*tables, loads_dev, *pairs, x, w_gate, w_up,
                             w_down, M=rows_bucket(loads, blk), blk=blk,

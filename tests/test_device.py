@@ -132,7 +132,7 @@ def test_claim_schedule_reads_back_what_the_kernel_wrote(technique, resumed):
               i_slot=0, lp_slot=1, interpret=True)
 
     out = persistent.protocol_call(slab, jnp.asarray(csum), **kw)
-    assert len(out) == 2
+    assert len(out) == 3  # the slab, the packed schedule, the tables
     assert out[1].dtype == jnp.int32 and out[1].shape == (4 * S + 2 * P,)
 
     _, steps, workers, starts, sizes, clocks, counts = (
@@ -150,6 +150,94 @@ def test_claim_schedule_reads_back_what_the_kernel_wrote(technique, resumed):
     assert sched.clocks.tobytes() == clocks.tobytes()  # bit for bit
     assert len(np.unique(sched.clocks)) > 1  # the costs are not uniform
     assert int(np.asarray(sched.slab)[1]) >= N
+
+
+def _host_walk(technique, N, P, chunk, costs, k):
+    """The claims of the closed-form plan from its ``k``-th on, each to the
+    earliest-free worker (float32 clocks, ties to the lowest index), as
+    per-worker lists of ``(start, size)`` in protocol order."""
+    sizes, starts = plan(host_spec(technique, N, P, chunk=chunk))
+    csum = np.zeros(N + 1, np.float32)
+    np.cumsum(np.asarray(costs, np.float64), out=csum[1:])
+    clocks = np.zeros(P, np.float32)
+    lists = [[] for _ in range(P)]
+    for st, sz in zip(starts[k:], sizes[k:]):
+        w = int(np.argmin(clocks))
+        clocks[w] = np.float32(clocks[w] + (csum[st + sz] - csum[st]))
+        lists[w].append((int(st), int(sz)))
+    return lists, starts
+
+
+@pytest.mark.parametrize("resumed", [False, True], ids=["fresh", "resumed"])
+@pytest.mark.parametrize("technique", DEVICE_TECHNIQUES)
+def test_device_tables_are_the_host_walk(technique, resumed):
+    """The per-worker tables ``protocol_call`` builds on the device hold
+    each worker's claims in protocol order, then zero-sized padding, and
+    are as wide as the plan: every worker fits, even one that takes all
+    claims of a run of zero-cost tiles."""
+    import jax.numpy as jnp
+
+    from repro.device import plan_claims
+
+    N, P, chunk = 150, 3, 3
+    costs = np.linspace(1.0, 3.0, N) ** 2
+    costs[40:90] = 0.0
+    k, slab = 0, None
+    if resumed:  # the first 4 claims already happened
+        _, starts = _host_walk(technique, N, P, chunk, costs, 0)
+        k = min(4, len(starts) - 1)
+        slab = jnp.array([k, starts[k]], jnp.int32)
+    lists, _ = _host_walk(technique, N, P, chunk, costs, k)
+    sched = claim_schedule(technique, N, P, chunk=chunk, costs=costs,
+                           slab=slab)
+    nclaims, starts, sizes = (np.asarray(t) for t in sched.tables)
+    C = plan_claims(technique, N, P, chunk)
+    assert starts.shape == sizes.shape == (P, C)
+    assert C >= max(map(len, lists))
+    assert nclaims.tolist() == [len(rows) for rows in lists]
+    for w, rows in enumerate(lists):
+        n = len(rows)
+        assert list(zip(starts[w, :n].tolist(), sizes[w, :n].tolist())) \
+            == rows
+        assert not sizes[w, n:].any() and not starts[w, n:].any()
+    assert nclaims.tolist() == sched.counts.tolist()
+
+
+@pytest.mark.parametrize("technique", DEVICE_TECHNIQUES)
+def test_a_resumed_loop_takes_no_more_claims_than_the_plan(technique):
+    """From every state the protocol leaves the counters in (the plan's
+    first ``k`` claims granted), the rest of the loop fits the tables'
+    width: zero costs send every claim to one worker."""
+    import jax.numpy as jnp
+
+    from repro.device import plan_claims
+
+    N, P = 97, 4
+    C = plan_claims(technique, N, P, 1)
+    _, starts = _host_walk(technique, N, P, 1, np.ones(N), 0)
+    assert C == len(starts)
+    for k in range(C + 1):
+        lp = int(starts[k]) if k < C else N
+        sched = claim_schedule(technique, N, P, costs=np.zeros(N),
+                               slab=jnp.array([k, lp], jnp.int32))
+        nclaims = np.asarray(sched.tables[0])
+        assert sched.n_steps == C - k == int(nclaims.sum())
+        assert nclaims.tolist() == [C - k] + [0] * (P - 1)
+
+
+def test_counters_off_the_plan_fail_on_read_not_in_silence():
+    """Counters set by hand past the plan's step index grant more claims
+    than the tables hold; the host view says so instead of a schedule
+    whose compute dropped claims."""
+    import jax.numpy as jnp
+
+    from repro.device import plan_claims
+
+    sched = claim_schedule("gss", 100, 4,
+                           slab=jnp.array([40, 0], jnp.int32))
+    assert sched.tables[1].shape == (4, plan_claims("gss", 100, 4))
+    with pytest.raises(RuntimeError, match="not left by"):
+        sched.starts
 
 
 def test_schedule_timeline_consistency():

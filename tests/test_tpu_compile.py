@@ -65,7 +65,7 @@ def test_protocol_kernel_compiles(one_chip, technique):
     import jax.numpy as jnp
 
     from repro.core.chunk_calculus import max_steps_bound
-    from repro.device import host_spec
+    from repro.device import host_spec, plan_claims
     from repro.device.persistent import protocol_call
 
     S = int(max_steps_bound(host_spec(technique, N_TILES, P)))
@@ -75,9 +75,13 @@ def test_protocol_kernel_compiles(one_chip, technique):
     text = _compile(one_chip, fn, ((256,), jnp.int32),
                     ((N_TILES + 1,), jnp.float32))
     assert "%dls_protocol" in text  # the kernel's name in the device trace
-    # two outputs: the slab, and the schedule packed for one read-back
+    # the slab, the schedule packed for one read-back, and the per-worker
+    # claim tables as wide as the plan, for the compute kernel
+    C = plan_claims(technique, N_TILES, P)
     header = re.sub(r"\{[^{}]*\}", "", text.splitlines()[0])  # no layouts
-    assert f"->(s32[256], s32[{4 * S + 2 * P}])" in header
+    assert (f"->(s32[256], s32[{4 * S + 2 * P}], s32[{P}], s32[{P},{C}], "
+            f"s32[{P},{C}])") in header
+    assert " scatter(" not in text
 
 
 def test_static_mandelbrot_compiles(one_chip):

@@ -24,16 +24,20 @@ P, TILE = 8, 8
 
 CLAIM = {"repro.claim.costs": "repro.claim",
          "repro.claim.launch": "repro.claim",
-         "repro.claim.readback": "repro.claim",
          "repro.claim": None, "repro.tables": None,
          "repro.compute.launch": None}
+# the schedule is read back where its host view is first asked for: by
+# the session's report plane, or after the compute kernel has run
 NESTING = {
     "mandelbrot": dict(CLAIM, **{"repro.session.open": None,
-                                 "repro.report": None}),
-    "attention": dict(CLAIM, **{"repro.tile_costs": None}),
+                                 "repro.report": None,
+                                 "repro.claim.readback": "repro.report"}),
+    "attention": dict(CLAIM, **{"repro.tile_costs": None,
+                                "repro.claim.readback": None}),
     "moe": dict(CLAIM, **{"repro.route": None,
                           "repro.route.readback": "repro.route",
-                          "repro.tile_costs": None}),
+                          "repro.tile_costs": None,
+                          "repro.claim.readback": None}),
 }
 
 
@@ -80,20 +84,36 @@ def _mandel_drain(N, technique):
     return sched
 
 
-def _attention_drain():
+def _attention_launch():
+    """Self-scheduled attention, launched: ``(out, schedule)``."""
     B, H, T, D = 2, 2, 256, 128
     q, k, v = (jax.random.normal(key, (B, H, T, D))
                for key in jax.random.split(jax.random.key(0), 3))
-    out, sched = flash_attention_persistent(
+    return flash_attention_persistent(
         q, k, v, lengths=[256, 100], causal=True, technique="gss",
         workers=P, blk_q=128, blk_k=128)
+
+
+def _read_after_compute(launch):
+    """One drain as the chip benchmark runs it: launch, wait for the
+    output, then read the schedule's host view."""
+    out, sched = launch()
     jax.block_until_ready(out)
+    sched.starts
     return sched
 
 
+def _attention_drain():
+    return _read_after_compute(_attention_launch)
+
+
 def _moe_drain():
+    return _read_after_compute(_moe_launch)
+
+
+def _moe_launch():
     """One chip's 4 of 16 experts, top 2 of 16: expert 1 takes every
-    token, expert 2 none."""
+    token, expert 2 none; launched: ``(y, schedule)``."""
     T, d, F, E = 40, 128, 128, 4
     ks = jax.random.split(jax.random.key(1), 4)
     x = jax.random.normal(ks[0], (T, d))
@@ -102,11 +122,9 @@ def _moe_drain():
     ids = np.stack([np.full(T, 1), 4 + np.arange(T) % 12], 1)
     ids[:7, 1] = 0  # 7 rows for expert 0, 0 for 2, 3 for 3
     ids[7:10, 1] = 3
-    y, sched = moe_experts_persistent(x, wg, wu, wd, ids.astype(np.int32),
-                                      np.full((T, 2), 0.5, np.float32),
-                                      held=range(4), workers=P, blk=8)
-    jax.block_until_ready(y)
-    return sched
+    return moe_experts_persistent(x, wg, wu, wd, ids.astype(np.int32),
+                                  np.full((T, 2), 0.5, np.float32),
+                                  held=range(4), workers=P, blk=8)
 
 
 DRAINS = {"mandelbrot": lambda: _mandel_drain(81, "gss"),
@@ -127,10 +145,12 @@ def test_claim_counters_are_the_grants_and_the_loop_length(tmp_path):
     S = int(max_steps_bound(host_spec("gss", 81, P)))
     assert claim["stats"] == {"claims": sched.n_steps, "steps": S}
     assert (sched.n_steps, S) == (17, 100)
-    # the schedule comes back as one packed int32 vector of 4*S + 2*P
+    # the schedule comes back as one packed int32 vector of 4*S + 2*P,
+    # read by the report plane before the compute kernel is launched
     (readback,) = [s for s in spans if s["name"] == "repro.claim.readback"]
-    assert _parent(readback, spans) == "repro.claim"
-    assert readback["stats"] == {"arrays": 1, "bytes": 4 * (4 * S + 2 * P)}
+    assert _parent(readback, spans) == "repro.report"
+    assert readback["stats"] == {"arrays": 1, "bytes": 4 * (4 * S + 2 * P),
+                                 "after_launch": 0}
 
 
 def test_route_readback_counts_the_held_pairs_once(tmp_path):
@@ -169,7 +189,55 @@ def test_span_count_per_drain_does_not_grow_with_the_claims(tmp_path):
     (readback,) = [s for s in spans_many
                    if s["name"] == "repro.claim.readback"]
     assert readback["stats"] == {"arrays": 1,
-                                 "bytes": 4 * (4 * 1296 + 2 * P)}
+                                 "bytes": 4 * (4 * 1296 + 2 * P),
+                                 "after_launch": 0}
+
+
+LAUNCHES = {"attention": _attention_launch, "moe": _moe_launch}
+
+
+@pytest.mark.parametrize("path", sorted(LAUNCHES))
+def test_compute_is_launched_before_the_schedule_is_read(tmp_path, path):
+    """Under the profiler, the wrapper launches the compute kernel on the
+    device-built tables and returns without reading the schedule back."""
+    def launch():
+        out, sched = LAUNCHES[path]()
+        jax.block_until_ready(out)
+        return sched
+
+    sched, spans = _traced(tmp_path, launch)
+    names = [s["name"] for s in spans]
+    assert "repro.compute.launch" in names
+    assert "repro.claim.readback" not in names
+    assert "_host" not in vars(sched)
+    assert sched.launched
+
+
+@pytest.mark.parametrize("path", sorted(LAUNCHES))
+def test_the_wrappers_return_with_the_schedule_unread(path):
+    assert not tracing.enabled()
+    out, sched = LAUNCHES[path]()
+    assert "_host" not in vars(sched) and sched.launched
+    jax.block_until_ready(out)
+    assert int(sched.counts.sum()) == sched.n_steps  # now read back
+
+
+@pytest.mark.parametrize("path", sorted(LAUNCHES))
+def test_a_read_after_the_compute_launch_counts_after_launch(tmp_path,
+                                                             path):
+    sched, spans = _traced(tmp_path, DRAINS[path])
+    (launch,) = [s for s in spans if s["name"] == "repro.compute.launch"]
+    (readback,) = [s for s in spans if s["name"] == "repro.claim.readback"]
+    assert readback["start"] >= launch["end"]
+    S = (sched.packed.shape[0] - 2 * P) // 4
+    assert readback["stats"] == {"arrays": 1, "bytes": 4 * (4 * S + 2 * P),
+                                 "after_launch": 1}
+    # the claims counter is the plan's count, exact with no read-back,
+    # and the tables handed to the compute kernel are that wide
+    (tables,) = [s for s in spans if s["name"] == "repro.tables"]
+    assert tables["stats"] == {"width": sched.n_steps}
+    (claim,) = [s for s in spans if s["name"] == "repro.claim"]
+    assert claim["stats"] == {"claims": sched.n_steps, "steps": S}
 
 
 class _Jitted:
