@@ -1,5 +1,8 @@
 """Benchmark aggregator: one section per paper table/figure + system benches.
 
+``benchmarks/`` holds DES and host-runtime studies and times nothing the
+chip runs; speed is ``chipbench/`` and ``PERF_LEDGER.jsonl``.
+
 ``python -m benchmarks.run``              -- quick mode (CI-friendly)
 ``python -m benchmarks.run --full``       -- paper-scale DES grids
 ``python -m benchmarks.run --list``       -- show the registry
@@ -50,30 +53,6 @@ def _replay(quick: bool) -> None:
     replay_predict.main(quick=quick)
 
 
-def _sim_sweep(quick: bool) -> None:
-    from benchmarks import sim_sweep
-
-    sim_sweep.main(quick=quick)
-
-
-def _sim_fast(quick: bool) -> None:
-    from benchmarks import sim_fast
-
-    sim_fast.main(quick=quick)
-
-
-def _kernels(quick: bool) -> None:
-    from benchmarks import kernels_bench
-
-    kernels_bench.main(quick=quick)
-
-
-def _kernels_selfsched(quick: bool) -> None:
-    from benchmarks import kernels_selfsched
-
-    kernels_selfsched.main(quick=quick)
-
-
 def _pt_contention(quick: bool) -> None:
     from benchmarks import pt_contention
 
@@ -84,20 +63,6 @@ def _serving_slo(quick: bool) -> None:
     from benchmarks import serving_slo
 
     serving_slo.main(quick=quick)
-
-
-def _roofline(quick: bool) -> None:
-    try:
-        from benchmarks import roofline
-
-        rows = roofline.load_all()
-        if rows:
-            print(roofline.table(rows))
-        else:
-            print("# no dry-run artifacts found; run "
-                  "python -m repro.launch.dryrun --all first")
-    except Exception as e:  # noqa: BLE001
-        print(f"# roofline unavailable: {e}")
 
 
 #: (name, section header, runner) -- selection surface for --list/--only.
@@ -112,23 +77,12 @@ BENCHMARKS = (
     ("overhead", "Scheduling overhead + scalability", _overhead),
     ("replay_predict",
      "Replay: predicted vs native + technique=auto selection", _replay),
-    ("sim_sweep",
-     "Batched sweeps: serial vs simulate_many on the predict roster",
-     _sim_sweep),
-    ("sim_fast",
-     "Vectorized DES fast path vs event kernel (>=10x contended pin)",
-     _sim_fast),
-    ("kernels", "Kernels (interpret mode; see header caveat)", _kernels),
-    ("kernels_selfsched",
-     "Self-scheduled persistent grids vs static (device window protocol)",
-     _kernels_selfsched),
     ("pt_contention",
      "pt: measured RMW latency / contention + DES prediction pin",
      _pt_contention),
     ("serving_slo",
      "Serving SLO: online re-selection vs fixed techniques under overload",
      _serving_slo),
-    ("roofline", "Roofline (from dry-run artifacts, if present)", _roofline),
 )
 
 

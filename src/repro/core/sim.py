@@ -155,8 +155,7 @@ class SimResult:
         )
 
 
-def simulate(cf: SimConfig, engine: str = "auto",
-             backend: str = "numpy") -> SimResult:
+def simulate(cf: SimConfig, engine: str = "auto") -> SimResult:
     """Run one configuration through the unified DES.
 
     ``engine="auto"`` routes qualifying configs (non-adaptive,
@@ -167,7 +166,7 @@ def simulate(cf: SimConfig, engine: str = "auto",
     """
     from repro.sim.run import simulate as _simulate
 
-    return _simulate(cf, engine=engine, backend=backend)
+    return _simulate(cf, engine=engine)
 
 
 def simulate_many(configs: Sequence[SimConfig], workers=None,

@@ -1,1 +1,1 @@
-"""Launchers: mesh, dry-run, train, serve."""
+"""Launchers: device hierarchy, train, serve."""

@@ -1,43 +1,11 @@
-"""Production mesh construction.
+"""Device hierarchy over the process's accelerators.
 
-IMPORTANT: this module must never touch jax device state at import time --
-``make_production_mesh`` is a function, and the 512-device host-platform
-override happens in dryrun.py's first two lines, before any jax import.
+This module never touches jax device state at import time: the devices
+are read when ``make_device_hierarchy`` is called.
 """
 from __future__ import annotations
 
 import jax
-
-
-def make_production_mesh(*, multi_pod: bool = False):
-    """The target deployment mesh.
-
-    Single pod: 256 chips as (data=16, model=16).
-    Multi-pod:  2 pods x 256 chips as (pod=2, data=16, model=16) -- the
-    "pod" axis carries pure data parallelism (gradient all-reduce over DCI),
-    "data" is the in-pod FSDP/batch axis, "model" is TP/EP.
-    """
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
-
-
-def make_test_mesh(devices: int, *, multi_pod: bool = False):
-    """Scaled-down mesh with the same axis structure (CI / unit tests)."""
-    if multi_pod:
-        assert devices % 2 == 0 and devices >= 4
-        rest = devices // 2
-        model = _largest_factor_leq(rest, int(rest ** 0.5))
-        return jax.make_mesh((2, rest // model, model), ("pod", "data", "model"))
-    model = _largest_factor_leq(devices, int(devices ** 0.5))
-    return jax.make_mesh((devices // model, model), ("data", "model"))
-
-
-def _largest_factor_leq(n: int, k: int) -> int:
-    for f in range(min(k, n), 0, -1):
-        if n % f == 0:
-            return f
-    return 1
 
 
 def make_device_hierarchy(global_window=None, capacity: int = 256):

@@ -8,7 +8,6 @@ Multi-host deployment notes (real cluster):
   * run one process per host with jax.distributed.initialize(); the DLS
     sampler then uses the KVStoreWindow automatically (window="auto"),
   * add --mesh to shard params/steps over the local device mesh.
-The dry-run (dryrun.py) is the scale-validation path for the 512-chip mesh.
 """
 from __future__ import annotations
 

@@ -1,6 +1,6 @@
 """Family-dispatching facade: one API for all ten architectures.
 
-The launcher, trainer, server, and dry-run all talk to this module only.
+The launchers, trainer and server talk to this module only.
 """
 from __future__ import annotations
 
@@ -21,7 +21,7 @@ def init_params(key, cfg):
 
 
 def abstract_params(cfg, seed: int = 0):
-    """Parameter pytree of ShapeDtypeStructs -- no allocation (dry-run path)."""
+    """Parameter pytree of ShapeDtypeStructs -- no allocation (abstract shapes)."""
     return jax.eval_shape(lambda k: init_params(k, cfg), jax.random.key(seed))
 
 

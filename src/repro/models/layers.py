@@ -5,7 +5,7 @@ an explicit ``ShardCtx`` so the same code runs unsharded on CPU and
 TP/FSDP-sharded on the production mesh.
 
 Attention has two execution backends:
-  * "xla"    -- einsum attention (default; what the dry-run compiles)
+  * "xla"    -- einsum attention (default)
   * "pallas" -- the fused flash-attention kernel (TPU production path;
                 validated in interpret mode by tests)
 """

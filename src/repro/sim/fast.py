@@ -37,11 +37,6 @@ uses: fast path iff the topology is one-sided/hierarchical, there are
 no perturbations, no chunk trace is requested, and neither the outer
 nor (hierarchical) inner technique is adaptive -- adaptive telemetry
 consumes the shared RNG mid-flight and must stay on the kernel.
-
-``backend="jax"`` additionally routes the one-sided batch round's
-float math through a ``jax.jit``-compiled core (requires x64); because
-XLA's scan association may differ in the last ulp it promises 1e-9
-relative -- not byte -- equivalence, and is opt-in only.
 """
 from __future__ import annotations
 
@@ -352,7 +347,7 @@ class _OneSided:
     so event ties break in the kernel's order.
     """
 
-    def __init__(self, cf, backend: str = "numpy", cache=None):
+    def __init__(self, cf, cache=None):
         spec = cf.spec
         self.N = spec.N
         self.P = spec.P
@@ -379,8 +374,6 @@ class _OneSided:
         self.oids = [cf.o_issue / s for s in self.s_list]
         self.tds_arr = np.asarray(self.tds)
         self.oids_arr = np.asarray(self.oids)
-        self.backend = backend
-        self._jax_core = _jax_batch_core() if backend == "jax" else None
         # mutable run state
         self.heap: List[tuple] = []
         self.waiters: List[tuple] = []
@@ -569,30 +562,20 @@ class _OneSided:
             exec_m = m2 & ~retire_m
             push = (m1 | exec_m).astype(np.int64)
             push_cum = np.cumsum(push)
-        # completion-time grid + spawn times (optionally jax-jitted)
-        if self._jax_core is not None:
-            f, t_spawn_exec_base, t_got = self._jax_core(
-                F0, o_rma, B, self.pref_arr, self.s_arr, self.o_net,
-                lp_before, np.minimum(lp_before + ks, N), pes, exec_m)
-            t_spawn = np.empty(B)
-            t_spawn[m1] = (f[m1] + self.o_net) + self.tds_arr[pes[m1]]
-            if exec_m.any():
-                t_spawn[exec_m] = t_spawn_exec_base[exec_m] \
-                    + self.oids_arr[pes[exec_m]]
-        else:
-            inc = np.full(B, o_rma)
-            inc[0] = F0 + o_rma
-            f = np.cumsum(inc)  # sequential adds == the kernel's clock
-            t_got = f + self.o_net
-            t_spawn = np.empty(B)
-            t_spawn[m1] = (f[m1] + self.o_net) + self.tds_arr[pes[m1]]
-            if exec_m.any():
-                a = lp_before[exec_m]
-                b = np.minimum(a + ks[exec_m], N)
-                et = (self.pref_arr[b] - self.pref_arr[a]) \
-                    / self.s_arr[pes[exec_m]]
-                t_spawn[exec_m] = (t_got[exec_m] + et) \
-                    + self.oids_arr[pes[exec_m]]
+        # completion-time grid + spawn times
+        inc = np.full(B, o_rma)
+        inc[0] = F0 + o_rma
+        f = np.cumsum(inc)  # sequential adds == the kernel's clock
+        t_got = f + self.o_net
+        t_spawn = np.empty(B)
+        t_spawn[m1] = (f[m1] + self.o_net) + self.tds_arr[pes[m1]]
+        if exec_m.any():
+            a = lp_before[exec_m]
+            b = np.minimum(a + ks[exec_m], N)
+            et = (self.pref_arr[b] - self.pref_arr[a]) \
+                / self.s_arr[pes[exec_m]]
+            t_spawn[exec_m] = (t_got[exec_m] + et) \
+                + self.oids_arr[pes[exec_m]]
         f_last = f[B - 1]
         # Sequence numbers: grant_j reserves 2, each prior push takes 1.
         # Cj[j] is the counter just before step j's events fire; the
@@ -890,46 +873,6 @@ class _OneSided:
             if parts else np.empty(0)
         return _result(self.finish, self.iters, self.n_claims, lat_all,
                        self.n_grants, 0)
-
-
-# ---------------------------------------------------------------------------
-# optional jax backend for the one-sided batch round
-# ---------------------------------------------------------------------------
-
-_JAX_CORE = None
-
-
-def _jax_batch_core():
-    """Build (once) the jitted round core; requires jax with x64."""
-    global _JAX_CORE
-    if _JAX_CORE is not None:
-        return _JAX_CORE
-    try:
-        import jax
-        import jax.numpy as jnp
-    except Exception as e:  # pragma: no cover - jax is a baked-in dep
-        raise RuntimeError(f"backend='jax' unavailable: {e}") from None
-    if not jax.config.jax_enable_x64:
-        raise RuntimeError(
-            "backend='jax' needs float64 event times: enable x64 "
-            "(jax.config.update('jax_enable_x64', True)) or use the "
-            "default numpy backend")
-
-    @jax.jit
-    def core(F0, o_rma, pref, speeds, o_net, a, b, pes, exec_m):
-        n = a.shape[0]
-        f = F0 + o_rma * jnp.cumsum(jnp.ones(n, jnp.float64))
-        t_got = f + o_net
-        et = (pref[b] - pref[a]) / speeds[pes]
-        return f, jnp.where(exec_m, t_got + et, 0.0), t_got
-
-    def run(F0, o_rma, B, pref, speeds, o_net, a, b, pes, exec_m):
-        f, base, t_got = core(F0, o_rma, pref, speeds, o_net, a, b, pes,
-                              exec_m)
-        return (np.asarray(f), np.asarray(base), np.asarray(t_got))
-
-    _JAX_CORE = run
-    return run
 
 
 # ---------------------------------------------------------------------------
@@ -1369,7 +1312,7 @@ class _TwoSided:
 # entry point
 # ---------------------------------------------------------------------------
 
-def simulate_fast(cf, backend: str = "numpy", cache=None) -> SimResult:
+def simulate_fast(cf, cache=None) -> SimResult:
     """Run a qualifying config through the fast path.
 
     Raises ``ValueError`` for configs that do not qualify (callers
@@ -1380,15 +1323,13 @@ def simulate_fast(cf, backend: str = "numpy", cache=None) -> SimResult:
     tables instead of recomputing them per candidate -- results are
     byte-identical with or without it.
     """
-    if backend not in ("numpy", "jax"):
-        raise ValueError(f"unknown backend {backend!r}")
     if not fast_qualifies(cf):
         raise ValueError(
             "config does not qualify for the fast path (adaptive "
             "technique, perturbations, or trace collection); use "
             "simulate() for automatic kernel fallback")
     if cf.impl == "one_sided":
-        return _OneSided(cf, backend=backend, cache=cache).run()
+        return _OneSided(cf, cache=cache).run()
     if cf.impl == "two_sided":
         return _TwoSided(cf, cache=cache).run()
     return _Hierarchical(cf, cache=cache).run()

@@ -104,7 +104,6 @@ class SweepCache:
 
 
 def simulate_fast_many(configs: Sequence, *, engine: str = "auto",
-                       backend: str = "numpy",
                        budget_s: Optional[float] = None,
                        cache: Optional[SweepCache] = None,
                        info: Optional[dict] = None) -> List:
@@ -137,7 +136,7 @@ def simulate_fast_many(configs: Sequence, *, engine: str = "auto",
         if i and deadline is not None and time.monotonic() > deadline:
             break  # budget spent: keep what's already evaluated
         if fast_qualifies(cf):
-            results[i] = simulate_fast(cf, backend=backend, cache=cache)
+            results[i] = simulate_fast(cf, cache=cache)
             engines[i] = "fast-batch"
         elif engine == "fast":
             raise ValueError(

@@ -28,14 +28,13 @@ ENGINES = {
 }
 
 
-def simulate(cf: SimConfig, engine: str = "auto",
-             backend: str = "numpy") -> SimResult:
-    """Run one configuration; ``engine``/``backend`` select the strategy."""
+def simulate(cf: SimConfig, engine: str = "auto") -> SimResult:
+    """Run one configuration; ``engine`` selects the strategy."""
     if engine == "auto":
         if fast_qualifies(cf):
-            return simulate_fast(cf, backend=backend)
+            return simulate_fast(cf)
     elif engine == "fast":
-        return simulate_fast(cf, backend=backend)
+        return simulate_fast(cf)
     elif engine != "kernel":
         raise ValueError(f"unknown engine {engine!r} "
                          "(expected 'auto', 'kernel', or 'fast')")

@@ -1,18 +1,17 @@
 """Per-architecture smoke tests: reduced config, one forward + train step +
 decode step on CPU, asserting output shapes and no NaNs.
 
-The FULL configs are exercised only by the dry-run (ShapeDtypeStruct level).
+The FULL configs are exercised only at the ShapeDtypeStruct level: the
+sharding rules below cover every parameter leaf of every architecture.
 
-The whole matrix jit-compiles ~2 minutes of models on CPU, so it lives in
-the slow tier; the tier-1 suite covers the model plane through the dry-run
-and the kernel/substrate tests.
+The model matrix jit-compiles ~2 minutes of models on CPU, so it lives in
+the slow tier; the tier-1 suite covers the model plane through the
+sharding-rule tests here and the kernel/substrate tests.
 """
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-
-pytestmark = pytest.mark.slow
 
 from repro.configs import ARCHS, applicable_shapes, get_config
 from repro.models import api
@@ -39,6 +38,7 @@ def _loss_fn(params, cfg, batch):
     return -jnp.take_along_axis(lp, labels[..., None], axis=-1).mean()
 
 
+@pytest.mark.slow
 @pytest.mark.parametrize("arch", sorted(ARCHS))
 def test_smoke_forward_and_train_step(arch):
     cfg = get_config(arch).reduced()
@@ -61,6 +61,7 @@ def test_smoke_forward_and_train_step(arch):
     assert bool(jnp.isfinite(loss2))
 
 
+@pytest.mark.slow
 @pytest.mark.parametrize("arch", sorted(ARCHS))
 def test_smoke_decode(arch):
     cfg = get_config(arch).reduced()
@@ -78,6 +79,7 @@ def test_smoke_decode(arch):
         tok = jnp.argmax(logits, -1).astype(jnp.int32)
 
 
+@pytest.mark.slow
 @pytest.mark.parametrize("arch", sorted(ARCHS))
 def test_decode_consistent_with_forward(arch):
     """Greedy decode logits == teacher-forced forward logits (same prefix)."""
@@ -102,6 +104,7 @@ def test_decode_consistent_with_forward(arch):
         np.asarray(lg2), np.asarray(full2[:, -1]), atol=2e-3, rtol=2e-3)
 
 
+@pytest.mark.slow
 def test_shape_cells_cover_assignment():
     """10 archs x shapes: long_500k only for sub-quadratic families."""
     total = sum(len(applicable_shapes(c)) for c in ARCHS.values())
@@ -112,6 +115,7 @@ def test_shape_cells_cover_assignment():
     assert subq == {"h2o-danube-3-4b", "mamba2-370m", "zamba2-2.7b"}
 
 
+@pytest.mark.slow
 def test_param_counts_match_names():
     expect = {
         "qwen3-moe-235b-a22b": (232e9, 0.1), "deepseek-67b": (67e9, 0.05),
@@ -124,6 +128,54 @@ def test_param_counts_match_names():
         assert abs(n - target) / target < tol + 0.05, (name, n)
 
 
+@pytest.mark.slow
 def test_moe_active_params():
     cfg = get_config("qwen3-moe-235b-a22b")
     assert cfg.active_param_count() < 0.12 * cfg.param_count()
+
+
+def test_shard_rules_cover_all_archs():
+    """Every param leaf of every arch gets a rank-matching PartitionSpec."""
+    import jax
+
+    from repro.configs import ARCHS
+    from repro.models import api
+    from repro.shard import params_pspecs
+
+    for name, cfg in ARCHS.items():
+        sds = api.abstract_params(cfg)
+        specs = params_pspecs(sds)
+        arr_leaves = jax.tree_util.tree_flatten(sds)[0]
+        from jax.sharding import PartitionSpec as P
+
+        spec_leaves = jax.tree_util.tree_flatten(
+            specs, is_leaf=lambda x: isinstance(x, P))[0]
+        assert len(arr_leaves) == len(spec_leaves)
+        for a, s in zip(arr_leaves, spec_leaves):
+            assert len(s) == a.ndim, (name, a.shape, s)
+
+
+def test_fix_divisibility_drops_bad_axes():
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import PartitionSpec as P
+
+    from repro.shard import fix_divisibility
+
+    mesh = jax.make_mesh((1,), ("model",))  # model size 1: everything divides
+    tree = {"a": jax.ShapeDtypeStruct((7, 8), jnp.float32)}
+    fixed = fix_divisibility(tree, {"a": P("model", None)}, mesh)
+    assert fixed["a"] == P("model", None)
+
+    # fake a 4-wide axis via test mesh helper semantics
+    import numpy as np
+
+    class FakeMesh:
+        axis_names = ("model",)
+        devices = np.empty((4,), dtype=object)
+
+    fixed = fix_divisibility(tree, {"a": P("model", None)}, FakeMesh())
+    assert fixed["a"] == P(None, None)  # 7 % 4 != 0 -> dropped
+    fixed = fix_divisibility({"a": jax.ShapeDtypeStruct((8, 7), jnp.float32)},
+                             {"a": P("model", None)}, FakeMesh())
+    assert fixed["a"] == P("model", None)

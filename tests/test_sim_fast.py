@@ -19,8 +19,7 @@ Three layers enforce it:
     determinism invariants of ``test_invariants.py``.
 
 Also covered: the ``_MTReplay`` Mersenne-Twister clone against CPython's
-``random.Random`` (the Lock-Polling grant order must be bit-identical),
-and the opt-in jax backend's 1e-9 relative contract.
+``random.Random`` (the Lock-Polling grant order must be bit-identical).
 """
 import dataclasses
 import json
@@ -166,47 +165,6 @@ def test_mt_replay_across_twist_boundary():
     rep = _MTReplay(42)
     for _ in range(5000):
         assert rep.randrange(3) == ref.randrange(3)
-
-
-# ---------------------------------------------------------------------------
-# jax backend: 1e-9 relative, opt-in, x64 only
-# ---------------------------------------------------------------------------
-
-def test_jax_backend_close():
-    jax = pytest.importorskip("jax")
-    was = jax.config.jax_enable_x64
-    jax.config.update("jax_enable_x64", True)
-    try:
-        cf = _random_config("ss", "one_sided", 64, 17,
-                            polling=False, continuous=True)
-        rn = simulate_fast(cf, backend="numpy")
-        rj = simulate_fast(cf, backend="jax")
-        np.testing.assert_allclose(rj.finish, rn.finish, rtol=1e-9)
-        np.testing.assert_allclose(rj.T_loop, rn.T_loop, rtol=1e-9)
-        assert rj.n_claims == rn.n_claims
-        assert list(rj.per_pe_iters) == list(rn.per_pe_iters)
-    finally:
-        jax.config.update("jax_enable_x64", was)
-
-
-def test_jax_backend_requires_x64():
-    jax = pytest.importorskip("jax")
-    if jax.config.jax_enable_x64:  # pragma: no cover - env-dependent
-        pytest.skip("x64 already on in this environment")
-    import repro.sim.fast as fast_mod
-    fast_mod._JAX_CORE = None  # drop any x64-built cache
-    cf = _random_config("ss", "one_sided", 64, 17,
-                        polling=False, continuous=True)
-    with pytest.raises(RuntimeError, match="x64"):
-        simulate_fast(cf, backend="jax")
-    fast_mod._JAX_CORE = None
-
-
-def test_unknown_backend_rejected():
-    cf = _random_config("ss", "one_sided", 4, 0,
-                        polling=True, continuous=True)
-    with pytest.raises(ValueError, match="backend"):
-        simulate_fast(cf, backend="cuda")
 
 
 # ---------------------------------------------------------------------------
