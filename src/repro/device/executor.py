@@ -3,11 +3,11 @@
 One persistent-kernel launch (``device/persistent.py``) runs the whole
 claim loop against the session's ``DeviceWindow`` slab; the executor then
 adopts the mutated counters back into the window (so ``drained()`` /
-``state()`` read the device truth), replays the granted claims into the
-session's metrics plane, and emits an ordinary ``SessionReport`` whose
-``chunk_times`` carry the modeled earliest-free-worker timeline -- which
-is exactly what ``repro.replay`` capture -> calibrate -> gantt consume,
-unchanged.
+``state()`` read the device truth), logs the granted claims into the
+session's metrics plane in one call (``log_schedule``, as columns), and
+emits an ordinary ``SessionReport`` whose ``chunk_times`` carry the
+modeled earliest-free-worker timeline -- which is exactly what
+``repro.replay`` capture -> calibrate -> gantt consume, unchanged.
 
 ``work_fn(start, stop)`` (optional) executes each chunk host-side in
 grant order -- the hook tests use to assert coverage; the persistent
@@ -21,7 +21,6 @@ from typing import Callable, Optional
 import numpy as np
 
 from repro import tracing
-from repro.core.scheduler import Claim
 
 from .persistent import claim_schedule, schedule_timeline
 from .runtime import DeviceRuntime
@@ -46,22 +45,16 @@ def execute_device(session, work_fn: Optional[Callable[[int, int], None]] = None
         chunk=spec.min_chunk, max_chunk=spec.max_chunk,
         costs=costs, slab=win.slab(), i_slot=i_slot, lp_slot=lp_slot,
         interpret=interpret)
-    with tracing.span("report"):
+    with tracing.span("report") as span:
         win.adopt(sched.slab, n_rmw=sched.n_rmw)
         rt.schedule = sched
 
         t0s, t1s = schedule_timeline(sched, costs=costs)
-        rows = []
-        for r in range(sched.n_steps):
-            w = int(sched.workers[r])
-            c = Claim(step=int(sched.steps[r]), start=int(sched.starts[r]),
-                      size=int(sched.sizes[r]))
-            session.log_claim(w, c)
-            if work_fn is not None:
-                work_fn(c.start, c.stop)
-            rows.append((w, c, float(t0s[r]), float(t1s[r])))
-        # record in canonical completion order (matches the sim executor)
-        for w, c, t0, t1 in sorted(rows, key=lambda x: (x[2], x[3], x[0])):
-            session.record_remote(w, c.size, t1 - t0, sched_seconds=0.0,
-                                  claim=c, t_start=t0, t_end=t1)
+        if work_fn is not None:
+            for start, size in zip(sched.starts.tolist(), sched.sizes.tolist()):
+                work_fn(start, start + size)
+        session.log_schedule(sched.workers, sched.steps, sched.starts,
+                             sched.sizes, t0s, t1s)
+        if tracing.enabled():
+            span.set_metadata(rows=sched.n_steps)
         return session.report("device", wall_time=sched.makespan())

@@ -372,20 +372,25 @@ def schedule_timeline(schedule: DeviceSchedule, costs=None):
 
     Recomputes the kernel's clock walk on the host (same csum, same
     order => same numbers) so executors can emit ``chunk_times`` rows
-    without shipping timestamps out of the kernel.
+    without shipping timestamps out of the kernel: a worker's clock is
+    the float32 running sum of its claims' costs in grant order, which
+    ``np.cumsum`` along a worker's row adds one claim at a time, as the
+    kernel does.
     """
-    N = schedule.N
+    N, P = schedule.N, schedule.P
     if costs is None:
         costs = np.ones(N, np.float64)
     csum = np.zeros(N + 1, np.float32)
     np.cumsum(np.asarray(costs, np.float64), out=csum[1:])
-    clocks = np.zeros(schedule.P, np.float32)
-    t0s = np.zeros(schedule.n_steps, np.float64)
-    t1s = np.zeros(schedule.n_steps, np.float64)
-    for r, (w, st, sz) in enumerate(
-            zip(schedule.workers, schedule.starts, schedule.sizes)):
-        cost = csum[st + sz] - csum[st]
-        t0s[r] = clocks[w]
-        clocks[w] = np.float32(clocks[w] + cost)
-        t1s[r] = clocks[w]
-    return t0s, t1s
+    workers, starts = schedule.workers, schedule.starts
+    # each claim's place in its worker's grant order, from 1
+    counts = np.bincount(workers, minlength=P)
+    order = np.argsort(workers, kind="stable")
+    place = np.empty_like(order)
+    place[order] = (np.arange(1, len(order) + 1)
+                    - np.repeat(np.cumsum(counts) - counts, counts))
+    clocks = np.zeros((P, counts.max(initial=0) + 1), np.float32)
+    clocks[workers, place] = csum[starts + schedule.sizes] - csum[starts]
+    clocks = np.cumsum(clocks, axis=1, dtype=np.float32)
+    return (clocks[workers, place - 1].astype(np.float64),
+            clocks[workers, place].astype(np.float64))

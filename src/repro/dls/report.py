@@ -6,6 +6,11 @@ aggregates both into the quantities the paper reports: number of
 scheduling steps, chunk-size series, per-PE iteration counts, and the
 load-imbalance coefficient of variation of per-PE busy/finish times.
 
+A session logs its claims two ways: one at a time from the host
+executors, and a whole device schedule at once as columns
+(``ScheduleColumns``).  A report's ``per_pe_claims`` and ``chunk_times``
+are built from that log when first read, and then kept.
+
 Reports are persistable: ``to_json()``/``from_json()`` round-trip every
 field under an explicit ``schema_version`` -- the ``repro.replay`` trace
 store is built on the per-chunk timing (``chunk_times``) carried here, so
@@ -15,8 +20,9 @@ a recorded run can be replayed/calibrated long after the session is gone
 from __future__ import annotations
 
 import dataclasses
+import functools
 import json
-from typing import List, Optional
+from typing import List, Optional, Sequence
 
 import numpy as np
 
@@ -28,6 +34,127 @@ from repro.core.weights import coefficient_of_variation
 REPORT_SCHEMA_VERSION = 1
 
 
+class _BuiltWhenRead:
+    """A ``SessionReport`` field that may be given the session's
+    ``ClaimLog``: the field's view of it is built on the first read and
+    kept in its place."""
+
+    def __init__(self, default=dataclasses.MISSING):
+        self.default = default
+
+    def __set_name__(self, owner, name):
+        self.name, self.key = name, "_" + name
+
+    def __get__(self, obj, owner=None):
+        if obj is None:  # the field's default; none makes it required
+            if self.default is dataclasses.MISSING:
+                raise AttributeError(self.name)
+            return self.default
+        value = obj.__dict__[self.key]
+        if isinstance(value, ClaimLog):
+            value = obj.__dict__[self.key] = getattr(value, self.name)()
+        return value
+
+    def __set__(self, obj, value):
+        obj.__dict__[self.key] = value
+
+
+@dataclasses.dataclass(frozen=True, eq=False)  # arrays: no value equality
+class ScheduleColumns:
+    """A device schedule's claims as columns, one row a claim, in grant
+    order, with its modeled timeline ``t0``/``t1``.
+
+    ``done`` lists the rows in completion order (by t0, t1, worker; ties
+    in grant order): the order of their ``chunk_times`` records.
+    """
+
+    pe: np.ndarray
+    step: np.ndarray
+    start: np.ndarray
+    size: np.ndarray
+    t0: np.ndarray
+    t1: np.ndarray
+    done: np.ndarray
+
+    @classmethod
+    def of(cls, workers, steps, starts, sizes, t0s, t1s) -> "ScheduleColumns":
+        cols = dict(pe=workers, step=steps, start=starts, size=sizes)
+        cols = {k: np.array(v, np.int64) for k, v in cols.items()}
+        cols.update(t0=np.array(t0s, np.float64), t1=np.array(t1s, np.float64))
+        cols["done"] = np.lexsort((cols["pe"], cols["t1"], cols["t0"]))
+        for v in cols.values():
+            v.flags.writeable = False  # a report shares them
+        return cls(**cols)
+
+    def add_busy(self, busy: np.ndarray) -> None:
+        """Add each claim's t1 - t0 to its PE's ``busy``, one at a time in
+        completion order (so the float sums are those of a per-claim
+        loop)."""
+        d = self.done
+        np.add.at(busy, self.pe[d], self.t1[d] - self.t0[d])
+
+    def add_to(self, claims: List[List[Claim]], times: List[dict]) -> None:
+        for pe, step, start, size in zip(self.pe.tolist(), self.step.tolist(),
+                                         self.start.tolist(),
+                                         self.size.tolist()):
+            claims[pe].append(Claim(step=step, start=start, size=size))
+        d = self.done
+        times.extend(
+            {"pe": pe, "step": step, "start": start, "size": size,
+             "t0": t0, "t1": t1, "lat": 0.0}
+            for pe, step, start, size, t0, t1 in zip(
+                self.pe[d].tolist(), self.step[d].tolist(),
+                self.start[d].tolist(), self.size[d].tolist(),
+                self.t0[d].tolist(), self.t1[d].tolist()))
+
+    def iters(self, P: int) -> np.ndarray:
+        return np.bincount(self.pe, weights=self.size,
+                           minlength=P).astype(np.int64)
+
+
+@dataclasses.dataclass(frozen=True)
+class HostClaims:
+    """Claims (per PE) and chunk timings logged one at a time."""
+
+    claims: List[List[Claim]]
+    times: List[dict]
+
+    def add_to(self, claims: List[List[Claim]], times: List[dict]) -> None:
+        for pe, per in enumerate(self.claims):
+            claims[pe].extend(per)
+        times.extend(self.times)
+
+    def iters(self, P: int) -> np.ndarray:
+        return np.array([sum(c.size for c in per) for per in self.claims]
+                        + [0] * (P - len(self.claims)), dtype=np.int64)
+
+
+@dataclasses.dataclass(frozen=True)
+class ClaimLog:
+    """A session's claim log as a report holds it: parts in log order."""
+
+    parts: Sequence  # of HostClaims | ScheduleColumns
+    P: int
+
+    def iters(self) -> np.ndarray:
+        return sum((part.iters(self.P) for part in self.parts),
+                   np.zeros(self.P, np.int64))
+
+    @functools.cached_property
+    def _views(self):
+        claims: List[List[Claim]] = [[] for _ in range(self.P)]
+        times: List[dict] = []
+        for part in self.parts:
+            part.add_to(claims, times)
+        return claims, times
+
+    def per_pe_claims(self) -> List[List[Claim]]:
+        return self._views[0]
+
+    def chunk_times(self) -> Optional[List[dict]]:
+        return self._views[1] or None
+
+
 @dataclasses.dataclass
 class SessionReport:
     """Aggregated metrics for one (possibly partial) session execution."""
@@ -37,7 +164,7 @@ class SessionReport:
     P: int
     runtime: str  # "one_sided" | "two_sided" | "hierarchical"
     executor: Optional[str]  # "serial"|"threads"|"processes"|"sim"|None (manual)
-    per_pe_claims: List[List[Claim]]
+    per_pe_claims: List[List[Claim]] = _BuiltWhenRead()
     per_pe_iters: np.ndarray  # iterations executed (sim) or claimed, per PE
     busy_time: np.ndarray  # seconds of work_fn execution per PE
     wall_time: float  # wall-clock of execute() (sim: virtual T_loop)
@@ -63,7 +190,7 @@ class SessionReport:
     # "t1", "lat"} with t0/t1 seconds since execute() began (the DES's
     # virtual clock for executor="sim") and lat the claim latency.  None
     # when the session was driven without timestamps (manual claim loops).
-    chunk_times: Optional[List[dict]] = None
+    chunk_times: Optional[List[dict]] = _BuiltWhenRead(default=None)
     # technique="auto" only: the selection record -- chosen technique,
     # predicted ranking (ordered sweep of simulated T_loop), seed, budget,
     # and workload source.  None for explicitly chosen techniques.

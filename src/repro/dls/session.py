@@ -34,7 +34,7 @@ from repro.core.rma import HierarchicalWindow, SimWindow
 from repro.core.scheduler import Claim, HierarchicalRuntime, OneSidedRuntime
 
 from .policies import UniformWeights, WeightPolicy, make_weight_policy
-from .report import SessionReport
+from .report import ClaimLog, HostClaims, ScheduleColumns, SessionReport
 from .runtime import Runtime, make_runtime
 
 _session_ids = itertools.count(1)
@@ -89,6 +89,9 @@ class DLSession:
         # Per-chunk timing records (repro.replay capture plane): appended in
         # completion order by ``record`` when executors pass timestamps.
         self._chunk_times: List[dict] = []
+        # What was logged before the claims above, in log order: earlier
+        # host claims and device schedules logged whole (``log_schedule``).
+        self._log_parts: list = []
         # technique="auto" selection record, set by ``loop`` (DESIGN.md
         # Sec. 9); threaded into every report.
         self.auto_decision: Optional[dict] = None
@@ -158,6 +161,30 @@ class DLSession:
         if self.record_metrics:
             self._ensure_pe(pe)
             self._claim_log[pe].append(c)
+
+    def log_schedule(self, workers, steps, starts, sizes, t0s, t1s) -> None:
+        """Log a whole device schedule: its claims, rows in grant order,
+        and their modeled timeline ``t0s``/``t1s``.
+
+        The same log and busy times as ``log_claim`` for each row in grant
+        order, then ``record_remote`` for each in completion order (by t0,
+        t1, worker), kept as columns: no per-claim object is built until a
+        report's ``per_pe_claims`` or ``chunk_times`` is read.
+        """
+        if not self.record_metrics:
+            return
+        cols = ScheduleColumns.of(workers, steps, starts, sizes, t0s, t1s)
+        if len(cols.pe):
+            self._ensure_pe(int(cols.pe.max()))
+        if any(self._claim_log) or self._chunk_times:
+            self._log_parts.append(HostClaims(self._claim_log,
+                                              self._chunk_times))
+            self._claim_log = [[] for _ in self._claim_log]
+            self._chunk_times = []
+        self._log_parts.append(cols)
+        busy = np.array(self._busy, np.float64)
+        cols.add_busy(busy)
+        self._busy = busy.tolist()
 
     def record(self, pe: int, iters: int, seconds: float,
                sched_seconds: float = 0.0, *,
@@ -269,6 +296,10 @@ class DLSession:
                wall_time: float = 0.0) -> SessionReport:
         """Snapshot the per-claim metrics collected so far."""
         rmw_g, rmw_l = self._rmw_counts()
+        log = ClaimLog((*self._log_parts,
+                        HostClaims([list(per) for per in self._claim_log],
+                                   list(self._chunk_times))),
+                       P=len(self._claim_log))
         return SessionReport(
             technique=self.spec.technique,
             N=self.spec.N,
@@ -277,16 +308,14 @@ class DLSession:
             executor=executor,
             min_chunk=self.spec.min_chunk,
             max_chunk=self.spec.max_chunk,
-            per_pe_claims=[list(per) for per in self._claim_log],
-            per_pe_iters=np.array(
-                [sum(c.size for c in per) for per in self._claim_log],
-                dtype=np.int64),
+            per_pe_claims=log,  # built when read
+            per_pe_iters=log.iters(),
             busy_time=np.asarray(self._busy, dtype=np.float64),
             wall_time=wall_time,
             n_rmw_global=rmw_g,
             n_rmw_local=rmw_l,
             adaptation=self._adaptation_trace(),
-            chunk_times=list(self._chunk_times) or None,
+            chunk_times=log,  # built when read
             auto_decision=self.auto_decision,
         )
 
@@ -332,14 +361,15 @@ class DLSession:
             self.runtime = HierarchicalRuntime(
                 self.spec, self.runtime.nodes, self.runtime.window,
                 inner_technique=self.runtime.inner_technique, loop_id=loop_id)
-        elif isinstance(self.runtime, OneSidedRuntime):
-            self.runtime = OneSidedRuntime(
+        elif isinstance(self.runtime, OneSidedRuntime):  # device ones too
+            self.runtime = type(self.runtime)(
                 self.spec, self.runtime.window, loop_id=loop_id)
         else:
             self.runtime.restore({"i": 0, "lp": 0})
         self._claim_log = [[] for _ in range(len(self._claim_log))]
         self._busy = [0.0] * len(self._busy)
         self._chunk_times = []
+        self._log_parts = []
         self._wire_outer_weights()  # fresh runtime objects need re-pointing
         self._rmw_base = self._rmw_snapshot()  # metrics restart at zero
         if not self.record_metrics and isinstance(self.policy, UniformWeights):

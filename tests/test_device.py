@@ -252,6 +252,158 @@ def test_schedule_timeline_consistency():
             assert t1s[a] <= t0s[b] + 1e-9
 
 
+def _timeline_by_loop(sched, costs):
+    """The kernel's clock walk replayed one claim at a time: the oracle of
+    the vectorised ``schedule_timeline``."""
+    csum = np.zeros(sched.N + 1, np.float32)
+    np.cumsum(np.asarray(costs, np.float64), out=csum[1:])
+    clocks = np.zeros(sched.P, np.float32)
+    t0s = np.zeros(sched.n_steps, np.float64)
+    t1s = np.zeros(sched.n_steps, np.float64)
+    for r, (w, st, sz) in enumerate(
+            zip(sched.workers, sched.starts, sched.sizes)):
+        cost = csum[st + sz] - csum[st]
+        t0s[r] = clocks[w]
+        clocks[w] = np.float32(clocks[w] + cost)
+        t1s[r] = clocks[w]
+    return t0s, t1s
+
+
+def _report_by_claim_loop(session, sched, costs, work_fn=None):
+    """The device executor's report plane as one call per claim: a
+    ``log_claim`` in grant order, then a ``record_remote`` in completion
+    order (the oracle of ``log_schedule``)."""
+    session.runtime.window.adopt(sched.slab, n_rmw=sched.n_rmw)
+    t0s, t1s = _timeline_by_loop(sched, costs)
+    rows = []
+    for r in range(sched.n_steps):
+        w = int(sched.workers[r])
+        c = Claim(step=int(sched.steps[r]), start=int(sched.starts[r]),
+                  size=int(sched.sizes[r]))
+        session.log_claim(w, c)
+        if work_fn is not None:
+            work_fn(c.start, c.stop)
+        rows.append((w, c, float(t0s[r]), float(t1s[r])))
+    for w, c, t0, t1 in sorted(rows, key=lambda x: (x[2], x[3], x[0])):
+        session.record_remote(w, c.size, t1 - t0, sched_seconds=0.0,
+                              claim=c, t_start=t0, t_end=t1)
+    return session.report("device", wall_time=sched.makespan())
+
+
+# uniform costs tie every worker's clock; the others have zero-cost
+# iterations, equal-cost runs and rising costs
+TIMELINE_COSTS = {
+    "uniform": lambda N: np.ones(N),
+    "zeros-and-ties": lambda N: np.where(np.arange(N) % 5 < 2, 0.0,
+                                         1.0 + (np.arange(N) // 40)),
+    "varying": lambda N: np.linspace(1.0, 3.0, N) ** 2,
+}
+
+
+@pytest.mark.parametrize("costs", sorted(TIMELINE_COSTS))
+@pytest.mark.parametrize("technique", ["ss", "gss", "fac2", "tss"])
+def test_schedule_timeline_is_the_clock_walk_bit_for_bit(technique, costs):
+    N, P = 200, 5
+    c = TIMELINE_COSTS[costs](N)
+    sched = claim_schedule(technique, N, P, costs=c)
+    t0s, t1s = schedule_timeline(sched, costs=c)
+    want0, want1 = _timeline_by_loop(sched, c)
+    assert t0s.tobytes() == want0.tobytes()
+    assert t1s.tobytes() == want1.tobytes()
+    # and the kernel's own clocks: each worker's last t1, in float32
+    last = np.zeros(P, np.float32)
+    for w, t1 in zip(sched.workers, t1s):
+        last[w] = t1
+    assert last.tobytes() == sched.clocks.tobytes()
+    assert float(t1s.max()) == sched.makespan()
+
+
+@pytest.mark.parametrize("costs", ["uniform", "varying"])
+@pytest.mark.parametrize("technique", ["ss", "gss", "fac2", "tss"])
+def test_device_report_is_the_per_claim_loops(technique, costs):
+    """The columnar report plane reports what one call per claim did:
+    equal ``to_dict()``, equal trace, and ``work_fn`` sees every chunk in
+    grant order."""
+    from repro.dls.report import SessionReport
+    from repro.replay import Trace
+
+    N, P = 160, 4
+    c = TIMELINE_COSTS[costs](N)
+    seen, want_seen = [], []
+    s = dls.loop(N, technique, P=P, runtime="device")
+    rep = dls.execute(s, lambda a, b: seen.append((a, b)),
+                      executor="device", costs=c)
+    sched = s.runtime.schedule
+    oracle = dls.loop(N, technique, P=P, runtime="device")
+    want = _report_by_claim_loop(oracle, sched, c,
+                                 lambda a, b: want_seen.append((a, b)))
+    assert seen == want_seen == [
+        (a, a + k) for a, k in zip(sched.starts.tolist(),
+                                   sched.sizes.tolist())]
+    assert rep.to_dict() == want.to_dict()
+    assert rep.to_json() == want.to_json()
+    assert rep.busy_time.tobytes() == want.busy_time.tobytes()
+    assert rep.per_pe_iters.dtype == want.per_pe_iters.dtype
+    assert rep.steps == want.steps == sched.n_steps
+    assert rep.claims == want.claims
+    assert Trace.from_report(rep) == Trace.from_report(want)
+    back = SessionReport.from_json(rep.to_json())
+    assert back.to_dict() == rep.to_dict()
+    assert back.claims == rep.claims
+
+
+def test_a_device_drain_builds_no_claim_objects():
+    """The drain logs its schedule as columns; the report's per-claim
+    views are built on their first read."""
+    from repro.dls.report import ClaimLog
+
+    s = dls.loop(96, "ss", P=4, runtime="device")
+    rep = s.execute(None, executor="device")
+    for key in ("_per_pe_claims", "_chunk_times"):
+        assert isinstance(rep.__dict__[key], ClaimLog)
+    assert rep.steps == 96 == len(rep.chunk_times)
+    assert isinstance(rep.__dict__["_per_pe_claims"], list)
+
+
+def test_a_device_report_keeps_its_log_across_a_reset_and_a_drain():
+    N, P = 120, 3
+    costs = np.linspace(1.0, 2.0, N)
+    s = dls.loop(N, "gss", P=P, runtime="device")
+    first = s.execute(None, executor="device", costs=costs)
+    want = _report_by_claim_loop(dls.loop(N, "gss", P=P, runtime="device"),
+                                 s.runtime.schedule, costs).to_dict()
+    s.reset()
+    second = s.execute(None, executor="device", costs=np.ones(N))
+    # the first report's views are built only now, after its log moved on
+    assert first.to_dict() == want
+    assert [c["t1"] for c in first.chunk_times] != \
+        [c["t1"] for c in second.chunk_times]
+    assert int(second.per_pe_iters.sum()) == N == len(
+        {a for c in second.claims for a in range(c.start, c.stop)})
+
+
+def test_a_session_with_host_claims_and_a_device_drain_reports_both():
+    N, P = 150, 3
+    s = dls.loop(N, "fac2", P=P, runtime="device")
+    host = [s.claim(pe) for pe in (0, 2, 2)]
+    s.record(2, host[1].size, 0.5, claim=host[1], t_start=0.0, t_end=0.5)
+    rep = s.execute(None, executor="device")
+    sched = s.runtime.schedule
+    assert rep.per_pe_claims[0][0] is host[0]
+    assert rep.per_pe_claims[2][:2] == host[1:]
+    dev = [Claim(int(st), int(a), int(k)) for st, a, k in
+           zip(sched.steps, sched.starts, sched.sizes)]
+    assert sorted(rep.claims, key=lambda c: c.step) == host + dev
+    assert int(rep.per_pe_iters.sum()) == N
+    assert rep.steps == 3 + sched.n_steps
+    assert rep.chunk_times[0] == {"pe": 2, "step": host[1].step,
+                                  "start": host[1].start,
+                                  "size": host[1].size, "t0": 0.0,
+                                  "t1": 0.5, "lat": 0.0}
+    assert len(rep.chunk_times) == 1 + sched.n_steps
+    assert rep.busy_time[2] >= 0.5
+
+
 # ---------------------------------------------------------------------------
 # DeviceWindow: the Window contract over a device-array slab
 # ---------------------------------------------------------------------------

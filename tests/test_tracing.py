@@ -153,6 +153,15 @@ def test_claim_counters_are_the_grants_and_the_loop_length(tmp_path):
                                  "after_launch": 0}
 
 
+@pytest.mark.parametrize("N,technique", [(81, "gss"), (144, "ss")])
+def test_the_report_plane_logs_every_claim_in_one_call(tmp_path, N,
+                                                        technique):
+    sched, spans = _traced(tmp_path, lambda: _mandel_drain(N, technique))
+    assert len(spans) == len(NESTING["mandelbrot"]) == 8
+    (report,) = [s for s in spans if s["name"] == "repro.report"]
+    assert report["stats"] == {"rows": sched.n_steps}
+
+
 def test_route_readback_counts_the_held_pairs_once(tmp_path):
     _, spans = _traced(tmp_path, _moe_drain)
     (readback,) = [s for s in spans if s["name"] == "repro.route.readback"]
